@@ -1,9 +1,12 @@
-"""Multi-device FlashSketch benchmark: shard-mapped sketching on a forced
-8-host-device mesh (the ``test_sharding_multidevice`` trick), plus the
-distributed sketch-and-precondition solver.
+"""Multi-device FlashSketch benchmark: shard-mapped sketching over every
+device of the host (4 chips on a v5e host), plus the distributed
+sketch-and-precondition solver.
 
     PYTHONPATH=src python -m benchmarks.dist_bench               # paper grid
     PYTHONPATH=src python -m benchmarks.dist_bench --tiny        # CI smoke
+
+Off the chip, give the CPU backend several devices before launching:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Writes ``BENCH_dist.json``.  Each row covers one (d, n, k, κ, dtype) cell:
 
@@ -11,7 +14,7 @@ Writes ``BENCH_dist.json``.  Each row covers one (d, n, k, κ, dtype) cell:
     path), column-sharded and batch-sharded applies against the
     single-device ``ops`` entry points.  These must hold BITWISE — the
     per-ℓ psum protocol guarantees it (see ``repro.distributed``).
-  * ``measured_*`` — wall-clock on THIS host.  8 emulated host devices
+  * ``measured_*`` — wall-clock on THIS host.  Emulated host devices
     share the same cores, so sharded wall-clock says nothing about real
     scaling; it is a smoke signal only.
   * ``modeled_*`` — TPU-v5e numbers priced from the LOWERING RECORDS of
@@ -24,49 +27,46 @@ Writes ``BENCH_dist.json``.  Each row covers one (d, n, k, κ, dtype) cell:
     model-vs-kernel contradiction).
 
 The run FAILS (non-zero exit) if any exactness gate is lost, if the
-modeled multi-chip scaling geomean drops below 1.5× at 8 devices, or if
+modeled multi-chip scaling geomean drops below 1.5× at P devices, or if
 the distributed solver fails to converge — CI runs ``--tiny`` as a
 regression gate.
 """
 from __future__ import annotations
 
-import os
+import argparse
+import json
+import sys
+from typing import Dict, List
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import argparse                                              # noqa: E402
-import json                                                  # noqa: E402
-import sys                                                   # noqa: E402
-from typing import Dict, List                                # noqa: E402
-
-import jax                                                   # noqa: E402
-import jax.numpy as jnp                                      # noqa: E402
-import numpy as np                                           # noqa: E402
-
-from benchmarks.common import geomean, time_fn               # noqa: E402
-from repro import engine                                     # noqa: E402
-from repro.distributed import (dist_sketch_precondition_lstsq,  # noqa: E402
+from benchmarks.common import geomean, time_fn
+from repro import engine
+from repro.compile_cache import enable_compile_cache
+from repro.distributed import (dist_sketch_precondition_lstsq,
                                plan_for_mesh,
                                sketch_apply_batched_sharded,
                                sketch_apply_colsharded,
                                sketch_apply_sharded)
-from repro.kernels import ops                                # noqa: E402
-from repro.launch import mesh as mesh_lib                    # noqa: E402
+from repro.kernels import ops
+from repro.launch import mesh as mesh_lib
 
-DEVICES = 8
 DTYPES = (None, "bfloat16")          # None = fp32 (the plan default)
 
 
-def bench_grid(cells, *, mesh, axis, iters=3, batch=DEVICES) -> List[Dict]:
+def bench_grid(cells, *, mesh, axis, iters=3) -> List[Dict]:
+    devices = mesh.shape[axis]
     rows: List[Dict] = []
     rng = np.random.default_rng(0)
     for d, n, k, kappa in cells:
         for dtype in DTYPES:
-            plan = plan_for_mesh(d, k, DEVICES, kappa=kappa, s=2, seed=0,
+            plan = plan_for_mesh(d, k, devices, kappa=kappa, s=2, seed=0,
                                  dtype=dtype or "float32")
             A = jnp.asarray(rng.normal(size=(d, n)).astype(np.float32))
             G = jnp.asarray(
-                rng.normal(size=(batch, d, max(1, n // batch)))
+                rng.normal(size=(devices, d, max(1, n // devices)))
                 .astype(np.float32))
 
             ref = ops.sketch_apply(plan, A)
@@ -95,13 +95,13 @@ def bench_grid(cells, *, mesh, axis, iters=3, batch=DEVICES) -> List[Dict]:
                 op="fwd", n=n, impl="pallas", tn=128))
             lwP = engine.lower(plan, engine.LaunchSpec(
                 op="fwd", n=n, impl="pallas", tn=128, shard="row",
-                devices=DEVICES))
+                devices=devices))
             c1 = engine.cost_of(lw1)
             cP = engine.cost_of(lwP)
             row = dict(
                 d=d, n=n, k=plan.k_pad, kappa=kappa,
                 dtype=dtype or "float32",
-                M=plan.M, Br=plan.Br, Bc=plan.Bc, devices=DEVICES,
+                M=plan.M, Br=plan.Br, Bc=plan.Bc, devices=devices,
                 exact_row_sharded=exact_row,
                 exact_col_sharded=exact_col,
                 exact_batch_sharded=exact_batch,
@@ -144,12 +144,14 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args(argv)
 
-    if jax.device_count() < DEVICES:
-        print(f"FAIL: need {DEVICES} devices (set XLA_FLAGS="
-              f"--xla_force_host_platform_device_count={DEVICES} before "
-              f"importing jax), got {jax.device_count()}", file=sys.stderr)
+    enable_compile_cache()
+    devices = jax.device_count()
+    if devices < 2:
+        print(f"FAIL: sharded apply needs several devices, got {devices} "
+              f"(off the chip, set XLA_FLAGS="
+              f"--xla_force_host_platform_device_count=8)", file=sys.stderr)
         return 1
-    mesh, axis = mesh_lib.make_mesh((DEVICES,), ("shard",)), "shard"
+    mesh, axis = mesh_lib.make_mesh((devices,), ("shard",)), "shard"
 
     if args.tiny:
         # d/k ≈ 512: deep enough in the paper's d >> k regime that the
@@ -171,10 +173,10 @@ def main(argv=None) -> int:
         "meta": {
             "backend": jax.default_backend(),
             "jax": jax.__version__,
-            "devices": DEVICES,
+            "devices": devices,
             "tiny": args.tiny,
             "note": ("row/col/batch-sharded FlashSketch vs single device on "
-                     f"{DEVICES} forced host devices; exact_* are "
+                     f"{devices} {jax.default_backend()} devices; exact_* are "
                      "array_equal gates (psum'd per-kappa partials); "
                      "measured_* is host wall-clock (emulated devices share "
                      "cores — smoke only); modeled_* is engine.cost_of of "
@@ -191,7 +193,7 @@ def main(argv=None) -> int:
     with open(args.out, "w") as f:
         json.dump(payload, f, indent=2)
     print(f"\nwrote {args.out}: modeled geomean x{geo_modeled:.2f} at "
-          f"{DEVICES} devices, exact={'OK' if all_exact else 'FAIL'}, "
+          f"{devices} devices, exact={'OK' if all_exact else 'FAIL'}, "
           f"solver={'OK' if solver['converged'] else 'FAIL'}")
 
     if not all_exact:
@@ -200,7 +202,7 @@ def main(argv=None) -> int:
         return 1
     if not (geo_modeled >= 1.5):
         print(f"FAIL: modeled multi-chip scaling {geo_modeled:.2f}x < 1.5x "
-              f"at {DEVICES} devices", file=sys.stderr)
+              f"at {devices} devices", file=sys.stderr)
         return 1
     if not solver["converged"]:
         print("FAIL: distributed sketch-and-precondition did not converge",

@@ -40,6 +40,7 @@ from repro.attribution.grass import sparsify_mask
 from repro.core.blockperm import make_plan
 from repro.kernels import ops
 from repro.roofline import sketch_model
+from repro.compile_cache import enable_compile_cache
 
 DTYPES = (None, "bfloat16")          # None = fp32 (the plan default)
 
@@ -170,6 +171,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="BENCH_grass.json")
     ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tiny:
         B_values, sparse_dims, kappas = (8,), (512,), (1,)

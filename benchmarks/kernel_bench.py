@@ -32,6 +32,7 @@ from repro import engine
 from repro.core.blockperm import SKETCH_VARIANTS as VARIANTS
 from repro.core.blockperm import make_plan
 from repro.kernels import ops, tune
+from repro.compile_cache import enable_compile_cache
 
 DTYPES = ("float32", "bfloat16")
 
@@ -50,7 +51,7 @@ def _operand(variant: str, plan, n: int, rng) -> np.ndarray:
 
 
 def bench_grid(d_values, k_values, n_for, *, kappa=4, s=2, seed=0,
-               tn=64, iters=3, autotune_first=False,
+               tn=None, iters=3, autotune_first=False,
                check_allclose=True) -> List[Dict]:
     rows: List[Dict] = []
     rng = np.random.default_rng(seed)
@@ -63,6 +64,11 @@ def bench_grid(d_values, k_values, n_for, *, kappa=4, s=2, seed=0,
                 plan = make_plan(d, k, kappa=kappa, s=s, seed=seed, dtype=dtype)
                 for variant in VARIANTS:
                     use_tn = v1_tn = tn
+                    if use_tn is None:
+                        # the tiles the engine resolves (chip-legal)
+                        use_tn = engine.lower(plan, engine.LaunchSpec(
+                            op=variant, n=n, impl="pallas")).tn
+                        v1_tn = tune.v1_default_tn(plan, n)
                     if autotune_first:
                         # each generation gets its own best tile — timing v1
                         # at v2's winner would bias the speedup toward v2
@@ -109,13 +115,15 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale (d, k) grid")
     ap.add_argument("--out", default="BENCH_kernel.json")
-    ap.add_argument("--tn", type=int, default=64)
+    ap.add_argument("--tn", type=int, default=None,
+                    help="column tile (default: the engine's choice)")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--autotune", action="store_true",
                     help="sweep tn per shape before timing")
     ap.add_argument("--tune-cache", default=None,
                     help="path to persist the autotuner cache")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.full:
         d_values = (16_384, 65_536, 131_072)

@@ -61,6 +61,7 @@ from repro.core import coherence  # noqa: E402
 from repro.core.variants import SKETCH_FAMILIES, make_sketch  # noqa: E402
 from repro.kernels import ops as kops  # noqa: E402
 from repro.solvers import lsqr  # noqa: E402
+from repro.compile_cache import enable_compile_cache
 
 TOL = 1e-6
 
@@ -201,6 +202,7 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=None,
                     help="timing repetitions per row (default 1 tiny / 3)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     missing = sorted(set(SKETCH_FAMILIES) - set(FAMILY_KWARGS))
     if missing:

@@ -50,6 +50,7 @@ from repro.solvers import (  # noqa: E402
     sketched_svd,
     sketch_precondition_lstsq,
 )
+from repro.compile_cache import enable_compile_cache
 
 KAPPAS = (1, 2, 4)
 # the precision-policy sweep: fp32/bf16 at the ill-conditioned regime,
@@ -260,6 +261,7 @@ def main(argv=None) -> None:
     ap.add_argument("--iters", type=int, default=1,
                     help="timing repetitions per row")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.full:
         problems = [(8192, 64), (16384, 128), (32768, 256)]

@@ -16,6 +16,7 @@ import time
 
 from benchmarks import common, grass_bench, roofline_table, sketch_tasks, speedup_table
 from benchmarks import theory_validation
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> None:
@@ -25,6 +26,7 @@ def main(argv=None) -> None:
                     help="comma list: gram,ose,ridge,solve,ablation,table1,"
                          "grass,theory,roofline")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name):

@@ -42,6 +42,7 @@ from repro.health.inject import (adversarial_input, corrupt_cache_file,
                                  inject_nan)
 from repro.kernels import tune
 from repro.serving import ManualClock, SketchRequest, SketchServer
+from repro.compile_cache import enable_compile_cache
 
 PARAMS = dict(kappa=2, s=2, seed=7)
 ADV_PARAMS = dict(kappa=1, s=1, seed=7)     # injectable plan class
@@ -167,6 +168,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tiny:
         d, n, k, rps, count = 256, 16, 64, 400.0, 80

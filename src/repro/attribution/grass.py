@@ -146,7 +146,6 @@ class GrassPipeline:
                 _, (feats, bad) = jax.lax.scan(
                     lambda car, xy: (car, chunk_feats(p, xy)), 0, (xc, yc))
             else:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def scan_local(p_, xcl, ycl):
@@ -155,11 +154,11 @@ class GrassPipeline:
                         0, (xcl, ycl))
                     return fb
 
-                feats, bad = shard_map(
+                feats, bad = jax.shard_map(
                     scan_local, mesh=mesh,
                     in_specs=(P(), P(shard_axis), P(shard_axis)),
                     out_specs=(P(shard_axis), P(shard_axis)),
-                    check_rep=False,
+                    check_vma=False,
                 )(p, xc, yc)
             # padded tail rows are sliced off BEFORE the bad-row count, so
             # a quarantined example is never double-counted via its padding

@@ -41,14 +41,35 @@ def _next_pow2(x: int) -> int:
     return 1 << max(0, (x - 1).bit_length())
 
 
-# VMEM working-set budget for the v2 fused kernel (out of ~16 MB/core),
-# shared with kernels.tune — the planner sizes blocks against it, the tuner
-# sizes tiles against it.
-VMEM_BUDGET_BYTES = 12 * 2**20
+# Scoped-VMEM budget of every kernel launch (a v5e core has 128 MiB of
+# VMEM; Mosaic's default scoped limit is 16 MiB).  The ONE number the
+# planner sizes blocks against, the tuner sizes tiles against, and the
+# kernels pass to Mosaic as ``vmem_limit_bytes``.  34 MiB is the smallest
+# whole MiB at which every default plan of the paper's (d, k) grid stays
+# fused at the narrowest tile: d=262144, k=64 is pinned at Br = s = 2
+# with Bc = 8192, and its κ=4 double-buffered (Bc, 128) input blocks alone
+# take 32 MiB.
+VMEM_BUDGET_BYTES = 34 * 2**20
 
-# Smallest column-tile width the tuner/planner will consider; the planner's
-# fit loop and kernels.tune's sweeps must agree on it.
-MIN_TILE_N = 8
+# TPU lane width: a block's last dimension must be a multiple of it or
+# the array's full extent.
+LANE = 128
+
+# Widest column chunk the in-kernel Φ build materializes at once.
+PHI_CHUNK = 512
+
+
+def phi_chunk(Bc: int) -> int:
+    """Column-chunk width of the in-kernel Φ build: the whole block when
+    ``Bc <= PHI_CHUNK``, else the widest lane multiple ≤ ``PHI_CHUNK``
+    dividing ``Bc`` (block widths above one lane are lane multiples)."""
+    if Bc <= PHI_CHUNK:
+        return Bc
+    for ch in range(PHI_CHUNK, 0, -LANE):
+        if Bc % ch == 0:
+            return ch
+    return Bc
+
 
 # The kernel variants (single source; tune/sketch_model/benchmarks reuse it).
 SKETCH_VARIANTS = ("fwd", "transpose", "blockrow")
@@ -80,32 +101,59 @@ GLOBAL_FAMILY_TAG = 0x610B
 GATHER_VARIANTS = ("fwd_gather", "blockrow_gather")
 
 
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) array as the chip lays it out: rows
+    padded to the dtype's sublane tile (8 rows of 32 bits), columns to
+    the 128-lane tile."""
+    sub = 8 * (4 // itemsize)
+    return (-(-rows // sub) * sub) * (-(-cols // LANE) * LANE) * itemsize
+
+
 def fused_variant_bytes(kappa: int, Br: int, Bc: int, tn: int,
                         itemsize: int = 4, variant: str = "fwd",
-                        phi_itemsize: Optional[int] = None) -> int:
-    """v2 VMEM footprint of one kernel variant: stacked Φ scratch +
-    double-buffered pipelined input blocks (or the row-gather scratch for
-    the ``*_gather`` variants) + output tile.  Must track the
-    scratch/pipeline layout in kernels/flashsketch.py.
+                        phi_itemsize: Optional[int] = None,
+                        gather_mask: bool = False) -> int:
+    """VMEM the chip's compiler allocates for one launch of a kernel
+    variant at tile width ``tn``: the (κ, Br, Bc) Φ scratch, the
+    double-buffered pipelined input and output blocks (or, for the
+    ``*_gather`` variants, the single (κ, Bc, tn) row-gather scratch), and
+    the in-kernel temporaries of the chunked Φ build and contraction.
+    Every array is counted at its padded chip layout (``_tile_bytes``).
+    Must track the scratch/pipeline layout in kernels/flashsketch.py;
+    ``tests/test_tpu_compile.py`` compiles the main-path launches with
+    this number as the Mosaic limit.
 
     ``itemsize`` is the streamed-operand width (``precision.itemsize``);
     ``phi_itemsize`` the Φ-scratch width, which differs under fp8
     policies (Φ is held in the compute dtype the fp8 stream is upcast
-    to, ``precision.compute_itemsize``) — defaults to ``itemsize``."""
-    phi = kappa * Br * Bc * (itemsize if phi_itemsize is None
-                             else phi_itemsize)
+    to, ``precision.compute_itemsize``) — defaults to ``itemsize``.
+    ``gather_mask``: the gather kernel zeroes padded rows (plan ``d <
+    d_pad``), one more (ch, tn) temporary."""
+    ci = itemsize if phi_itemsize is None else phi_itemsize
+    ch = phi_chunk(Bc)
+    phi = kappa * _tile_bytes(Br, Bc, ci)
+    # Φ build: iota, compare, select and accumulator at (Br, ch) fp32
+    build = 4 * _tile_bytes(Br, ch, 4)
     if variant == "transpose":
-        ins = 2 * kappa * Br * tn * itemsize
-        out = Bc * tn * 4
-    elif variant in GATHER_VARIANTS:
-        # input lives in HBM; rows are DMA'd into a single-buffered
-        # (κ·Bc, tn) gather scratch
-        ins = kappa * Bc * tn * itemsize
-        out = Br * tn * 4
-    else:                                   # fwd / blockrow
-        ins = 2 * kappa * Bc * tn * itemsize
-        out = Br * tn * 4
-    return phi + ins + out
+        ins = 2 * kappa * _tile_bytes(Br, tn, itemsize)
+        out = 2 * _tile_bytes(Bc, tn, 4)
+        # κ loaded (Br, tn) operands, the (ch, tn) fp32 chunk results and
+        # the transposed (ch, Br) Φ slice
+        temps = (kappa * _tile_bytes(Br, tn, ci)
+                 + 3 * _tile_bytes(ch, tn, 4) + 2 * _tile_bytes(ch, Br, 4))
+    else:
+        if variant in GATHER_VARIANTS:
+            # input lives in HBM; fp32 rows are DMA'd into a
+            # single-buffered (κ·Bc, 1, tn) gather scratch
+            ins = kappa * _tile_bytes(Bc, tn, 4)
+        else:                               # fwd / blockrow
+            ins = 2 * kappa * _tile_bytes(Bc, tn, itemsize)
+        out = 2 * _tile_bytes(Br, tn, 4)
+        # the loaded (ch, tn) operand chunk, its compute-dtype copy and
+        # fp32 pieces, plus the (Br, tn) fp32 accumulators
+        temps = (3 * _tile_bytes(ch, tn, 4) + 4 * _tile_bytes(Br, tn, 4)
+                 + (_tile_bytes(ch, tn, 4) if gather_mask else 0))
+    return phi + ins + out + build + temps
 
 
 def fused_working_set_bytes(kappa: int, Br: int, Bc: int, tn: int,
@@ -331,11 +379,12 @@ def make_plan(
             raise ValueError(f"s={s} must divide Br={Br} (both powers of two ok)")
     Bc = _aligned_bc(d, M)
     # Keep the fused v2 working set (stacked Φ ∝ κ·Br·Bc plus pipelined
-    # blocks ∝ Bc, see kernels/flashsketch) resident in VMEM by trading Br
-    # for M: halving Br doubles M and halves Bc, shrinking both terms while
-    # k_pad = M·Br is unchanged.  Only when the caller did not pin block_rows.
+    # blocks ∝ Bc, see kernels/flashsketch) resident in VMEM at the
+    # narrowest lane-aligned tile by trading Br for M: halving Br doubles M
+    # and halves Bc, shrinking both terms while k_pad = M·Br is unchanged.
+    # Only when the caller did not pin block_rows.
     if block_rows is None:
-        while (fused_working_set_bytes(kappa, Br, Bc, tn=MIN_TILE_N)
+        while (fused_working_set_bytes(kappa, Br, Bc, tn=LANE)
                > VMEM_BUDGET_BYTES
                and Br // 2 >= max(_next_pow2(s), 1)):
             Br //= 2
@@ -378,7 +427,7 @@ def _make_global_plan(d: int, k: int, *, s: int, seed: int,
     if block_rows is None:
         # κ = M tracks the split: the working set is evaluated at the
         # CURRENT M each iteration (Φ = M·Br·Bc shrinks as Br halves).
-        while (fused_working_set_bytes(M, Br, Bc, tn=MIN_TILE_N)
+        while (fused_working_set_bytes(M, Br, Bc, tn=LANE)
                > VMEM_BUDGET_BYTES and Br // 2 >= 1):
             Br //= 2
             M *= 2

@@ -238,6 +238,17 @@ def quantize_stream(x: jnp.ndarray, policy: Union[str, Precision],
     return jnp.where(up, hi, lo).astype(p.stream_dtype)
 
 
+def fp32_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` at full fp32 precision on every backend.
+
+    The TPU's default fp32 matmul rounds both inputs to bf16 (8 mantissa
+    bits): too coarse for an fp32 LSQR residual of 1e-6, and for an fp32
+    reference to check an fp32 kernel against.  The solvers' matvecs and Gram
+    products go through here; the kernels' own contractions pin the same
+    precision (``kernels.flashsketch._dot``)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def emulate_stream(x: jnp.ndarray, policy: Union[str, Precision],
                    *, seed: int = 0) -> jnp.ndarray:
     """Round ``x`` through the streaming dtype, returned as fp32.

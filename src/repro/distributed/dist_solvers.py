@@ -25,11 +25,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.blockperm import BlockPermPlan
-from repro.distributed.sharded_apply import (plan_for_mesh, shard_count,
+from repro.core.precision import fp32_matmul
+from repro.distributed.sharded_apply import (_jit_shard_map, plan_for_mesh,
+                                             shard_count,
                                              sketch_apply_sharded)
 from repro.kernels import ops
 from repro.solvers.sketch_precondition import (SolveResult,
@@ -55,14 +56,14 @@ def sharded_matvec_ops(A: jnp.ndarray, mesh, axis: str):
         raise ValueError(
             f"row-sharded matvec needs P | d: P={num}, d={A.shape[0]}")
 
-    mv = shard_map(
-        lambda Al, v: Al @ v, mesh=mesh,
+    mv = _jit_shard_map(
+        lambda Al, v: fp32_matmul(Al, v), mesh=mesh,
         in_specs=(P(axis, None), P(None)), out_specs=P(axis),
-        check_rep=False)
-    rmv = shard_map(
-        lambda Al, ul: jax.lax.psum(Al.T @ ul, axis), mesh=mesh,
+        check_vma=False)
+    rmv = _jit_shard_map(
+        lambda Al, ul: jax.lax.psum(fp32_matmul(Al.T, ul), axis), mesh=mesh,
         in_specs=(P(axis, None), P(axis)), out_specs=P(None),
-        check_rep=False)
+        check_vma=False)
     return (lambda v: mv(A, v)), (lambda u: rmv(A, u))
 
 

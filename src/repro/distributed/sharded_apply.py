@@ -35,7 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import hashing
@@ -48,6 +47,13 @@ from repro.kernels import ref as kref
 # The VMEM predicate is single-sourced in the lowering engine (shared with
 # ops dispatch); re-exported here because it is part of this package's API.
 partial_fits_vmem = lowering.partial_fits_vmem
+
+
+def _jit_shard_map(fn, **kwargs):
+    """``jax.shard_map`` compiled as ONE program: called eagerly, a
+    shard_map runs its body op by op, compiling a multi-device program
+    per primitive."""
+    return jax.jit(jax.shard_map(fn, **kwargs))
 
 
 def shard_count(mesh, axis: str) -> int:
@@ -77,17 +83,21 @@ def plan_for_mesh(
     seed: int = 0,
     dtype: str = "float32",
 ) -> BlockPermPlan:
-    """``make_plan`` with the block grid pinned so ``P | M``.
+    """``make_plan`` with a block grid that ``P`` divides.
 
-    The auto planner optimizes M for one chip; row-sharding additionally
-    needs the shard count (a power of two) to divide M.  This picks the
-    smallest ``B_r`` pin whose resulting grid satisfies both ``M ≥ P`` and
-    ``M ≥ κ``.  Tiny sketches (``k < P·s``) cannot host P shards and fail
-    ``check_row_partition`` downstream.
+    The auto planner's grid is kept when ``P | M``: it is the one sized
+    for one chip's VMEM, so the per-device partial kernel (one (B_r, B_c)
+    Φ tile) fits as well.  Otherwise ``B_r`` is pinned so the grid has
+    exactly ``M = max(P, κ)`` blocks (powers of two).  Tiny sketches
+    (``k < P·s``) cannot host P shards and fail ``check_row_partition``
+    downstream.
     """
     import math as _math
 
     from repro.core.blockperm import _next_pow2
+    plan = make_plan(d, k, kappa=kappa, s=s, seed=seed, dtype=dtype)
+    if plan.M % max(1, num_shards) == 0:
+        return plan
     m_target = max(_next_pow2(max(1, num_shards)), _next_pow2(max(1, kappa)))
     br = max(_next_pow2(_math.ceil(k / m_target)), _next_pow2(max(1, s)))
     return make_plan(d, k, kappa=kappa, s=s, seed=seed, block_rows=br,
@@ -178,16 +188,13 @@ def _partial_oracle(plan: BlockPermPlan, slab: jnp.ndarray,
                                     tables[2, ell])
             gathered = A_blocks[local]                    # (M, Bc, n)
             phi = kref._phi_rows_all_blocks(plan, h_of_g)  # (M, Br, Bc)
-            contrib = jnp.einsum(
-                "gbc,gcn->gbn", phi, gathered,
-                precision=jax.lax.Precision.HIGHEST)
+            contrib = kref.block_einsum("gbc,gcn->gbn", phi, gathered)
             parts.append(jnp.where(owned[:, None, None] == 1, contrib, 0.0))
         return jnp.stack(parts).reshape(plan.kappa, plan.k_pad, n)
     for ell in range(plan.kappa):
         phi = _phi_pairs(plan, tables[0, ell], tables[1, ell])
-        contrib = jnp.einsum(
-            "gbc,gcn->gbn", phi, A_blocks,
-            precision=jax.lax.Precision.HIGHEST)          # (M_loc, Br, n)
+        contrib = kref.block_einsum("gbc,gcn->gbn", phi,
+                                    A_blocks)             # (M_loc, Br, n)
         parts.append(contrib)
     return jnp.stack(parts).reshape(plan.kappa, M_loc * plan.Br, n)
 
@@ -247,8 +254,8 @@ def local_partial_apply(
     else:
         # ragged n is handled in-kernel — the slab is never column-padded
         parts = fsk.flashsketch_pallas_partial(
-            plan, slab, tables, tn=lw.tn,
-            rows_pattern=rows_pattern)[:, :, :n]
+            plan, slab, tables, tn=lw.tn, rows_pattern=rows_pattern,
+            interpret=lw.interpret)[:, :, :n]
     if rows_pattern:
         return parts                                      # already global
     # scatter the compact owned-pair rows into the zero global layout —
@@ -323,10 +330,10 @@ def sketch_apply_sharded(
         parts = jax.lax.psum(parts, axis)   # exact: one contributor/element
         return _fold_scale_truncate(parts, plan, scale)
 
-    return shard_map(
+    return _jit_shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis, None),), out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )(Ap)
 
 
@@ -354,10 +361,10 @@ def sketch_apply_colsharded(
     def shard_fn(A_loc):
         return ops.sketch_apply(plan, A_loc, impl, tn, dtype)
 
-    return shard_map(
+    return _jit_shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(None, axis),), out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(A)
 
 
@@ -399,8 +406,8 @@ def sketch_apply_batched_sharded(
         in_specs = (P(axis), P(None))
         args = (A, jnp.asarray(row_index, jnp.int32))
 
-    return shard_map(
+    return _jit_shard_map(
         shard_fn, mesh=mesh,
         in_specs=in_specs, out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )(*args)

@@ -6,11 +6,11 @@ v2 (default) — fused-κ single-write formulation:
     Program ``(g, j)`` owns output tile ``Y[g·B_r:(g+1)B_r, j·T_n:(j+1)T_n]``
     and receives all κ gathered input blocks ``A[π_ℓ(g)·B_c:…, j·T_n:…]``
     for ℓ = 1..κ via κ block-pipelined views of the same operand.
-  * The κ reduction happens **inside** the kernel: the stacked tile
-    ``[Φ_{g,π₁(g)} | … | Φ_{g,π_κ(g)}] ∈ (B_r, κ·B_c)`` is contracted
-    against the stacked input ``(κ·B_c, T_n)`` in a single MXU dot,
-    producing exactly **one** output write per tile — no κ grid revisits,
-    no output read-modify-writes.
+  * The κ reduction happens **inside** the kernel: the κ tiles
+    ``Φ_{g,π_ℓ(g)} ∈ (B_r, B_c)`` are contracted against their pipelined
+    input blocks ``(B_c, T_n)`` and summed in registers, producing exactly
+    **one** output write per tile — no κ grid revisits, no output
+    read-modify-writes, and no (κ·B_c, T_n) VMEM copy of the input.
   * The stacked Φ lives in VMEM scratch and depends only on ``g`` — it is
     rebuilt only at ``j == 0`` and reused across all n/T_n column tiles,
     amortizing the s hash passes (VPU work) by a factor of n/T_n.
@@ -20,11 +20,17 @@ v2 (default) — fused-κ single-write formulation:
     rounding at the cast (``_stream``).  In-kernel, fp8 tiles upcast to
     bf16 (exact) for the MXU and Φ is held in the compute dtype (entries
     ±1/0 are exact in every policy), while the MXU accumulates in fp32
-    (``preferred_element_type``).  This shrinks the dominant HBM term in
-    the paper's d ≫ k regime.
+    (``preferred_element_type``; fp32 operands contract at
+    ``Precision.HIGHEST``).  This shrinks the dominant HBM term in the
+    paper's d ≫ k regime.
+  * Chip constraints the engine (``kernels.lowering``) guarantees: every
+    tile width it picks is a multiple of 128 lanes or the launch's full
+    column count, and every launch fits ``VMEM_BUDGET_BYTES``, which is
+    also passed to Mosaic as the scoped-VMEM limit.  Φ is built in
+    lane-aligned column chunks so its hash temporaries stay small.
 
 v2-gather (``*_gather``) — the same fused-κ formulation with the input row
-gather folded INTO the kernel: the operand stays in HBM (``pltpu.ANY``)
+gather folded INTO the kernel: the operand stays in HBM (``pl.ANY``)
 and an arbitrary per-row index map (e.g. the GraSS sparsify mask) is
 scalar-prefetched; each program DMAs its κ·B_c masked rows straight into a
 VMEM gather scratch and contracts the cached stacked Φ against it.
@@ -56,7 +62,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
 from repro.core import precision as precision_mod
-from repro.core.blockperm import GLOBAL_FAMILY_TAG, BlockPermPlan
+from repro.core.blockperm import (GLOBAL_FAMILY_TAG, VMEM_BUDGET_BYTES,
+                                  BlockPermPlan, phi_chunk)
 from repro.kernels import ref as kref
 
 
@@ -142,12 +149,14 @@ def _transpose_phi_and_table(plan: BlockPermPlan):
 # In-kernel Φ construction (must match ref._phi_all_blocks bit-for-bit).
 # ---------------------------------------------------------------------------
 
-def _phi_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
-    """Φ_{g,h} ∈ (Br, Bc), entries ±1/0, built from hashes. g,h traced scalars."""
-    u = jax.lax.broadcasted_iota(jnp.int32, (1, plan.Bc), 1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, plan.Bc), 0)
+def _phi_tile(plan: BlockPermPlan, g, h, c0=0, width=None) -> jnp.ndarray:
+    """Columns ``[c0, c0 + width)`` of Φ_{g,h} ∈ (Br, Bc), entries ±1/0,
+    built from hashes (the whole tile by default).  g, h traced scalars."""
+    width = plan.Bc if width is None else width
+    u = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, width), 0)
     chunk = plan.chunk
-    phi = jnp.zeros((plan.Br, plan.Bc), jnp.float32)
+    phi = jnp.zeros((plan.Br, width), jnp.float32)
     for i in range(plan.s):
         hsh = hashing.hash_words(
             np.uint32(plan.seed),
@@ -155,31 +164,32 @@ def _phi_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
             h.astype(jnp.uint32),
             u.astype(jnp.uint32),
             np.uint32(i),
-        )                                              # (1, Bc)
+        )                                              # (1, width)
         rows = i * chunk + hashing.hash_mod(hsh, chunk)
         signs = hashing.hash_to_unit_sign(hsh)
         phi = phi + jnp.where(r_iota == rows, signs, 0.0)
     return phi
 
 
-def _phi_global_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
-    """Block (g, h) of a GLOBAL family's S (countsketch/graph), entries
-    ±1/0.  Nonzero i of global column ``h·Bc + u`` lands at GLOBAL row
-    ``i·(k_pad/s) + hash mod (k_pad/s)``; rows outside block g never match
-    the local row iota, so the masking is free.  Matches
-    ``core.blockperm.dense_global_block`` bit-for-bit."""
-    u = jax.lax.broadcasted_iota(jnp.int32, (1, plan.Bc), 1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, plan.Bc), 0)
+def _phi_global_tile(plan: BlockPermPlan, g, h, c0=0, width=None) -> jnp.ndarray:
+    """Columns ``[c0, c0 + width)`` of block (g, h) of a GLOBAL family's S
+    (countsketch/graph), entries ±1/0.  Nonzero i of global column
+    ``h·Bc + u`` lands at GLOBAL row ``i·(k_pad/s) + hash mod (k_pad/s)``;
+    rows outside block g never match the local row iota, so the masking
+    is free.  Matches ``core.blockperm.dense_global_block`` bit-for-bit."""
+    width = plan.Bc if width is None else width
+    u = c0 + jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, width), 0)
     chunk = plan.chunk                      # k_pad // s (global partition)
     gcol = h * plan.Bc + u                  # global column indices
-    phi = jnp.zeros((plan.Br, plan.Bc), jnp.float32)
+    phi = jnp.zeros((plan.Br, width), jnp.float32)
     for i in range(plan.s):
         hsh = hashing.hash_words(
             np.uint32(plan.seed),
             np.uint32(GLOBAL_FAMILY_TAG),
             gcol.astype(jnp.uint32),
             np.uint32(i),
-        )                                              # (1, Bc)
+        )                                              # (1, width)
         rows = i * chunk + hashing.hash_mod(hsh, chunk)
         local = rows - g * plan.Br
         signs = hashing.hash_to_unit_sign(hsh)
@@ -187,11 +197,13 @@ def _phi_global_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
     return phi
 
 
-def _phi_rows_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
-    """FLASHBLOCKROW pattern: s ±1 entries per *row*. Matches ref._phi_rows_all_blocks."""
+def _phi_rows_tile(plan: BlockPermPlan, g, h, c0=0, width=None) -> jnp.ndarray:
+    """FLASHBLOCKROW pattern (s ±1 entries per *row*), columns
+    ``[c0, c0 + width)``.  Matches ref._phi_rows_all_blocks."""
+    width = plan.Bc if width is None else width
     r = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, 1), 0)
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (plan.Br, plan.Bc), 1)
-    phi = jnp.zeros((plan.Br, plan.Bc), jnp.float32)
+    c_iota = c0 + jax.lax.broadcasted_iota(jnp.int32, (plan.Br, width), 1)
+    phi = jnp.zeros((plan.Br, width), jnp.float32)
     for t in range(plan.s):
         hsh = hashing.hash_words(
             np.uint32(plan.seed),
@@ -207,12 +219,29 @@ def _phi_rows_tile(plan: BlockPermPlan, g, h) -> jnp.ndarray:
     return phi
 
 
+def _build_phi(phi_ref, plan: BlockPermPlan, phi_fn, g, h) -> None:
+    """Write Φ_{g,h} into the (Br, Bc) ref ``phi_ref`` in lane-aligned
+    column chunks of ``phi_chunk(Bc)``: the hash/compare temporaries stay
+    (Br, chunk)-sized instead of (Br, Bc), which is what keeps the build
+    inside the VMEM budget at the paper's block widths."""
+    ch = phi_chunk(plan.Bc)
+
+    def body(c, carry):
+        c0 = pl.multiple_of(c * ch, ch)
+        phi_ref[:, pl.ds(c0, ch)] = phi_fn(plan, g, h, c0, ch).astype(
+            phi_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, plan.Bc // ch, body, 0)
+
+
 def stacked_phi(plan: BlockPermPlan, g, neighbors, *, rows_pattern: bool = False):
     """The fused tile [Φ_{g,h₁} | … | Φ_{g,h_κ}] ∈ (Br, κ·Bc).
 
-    Exactly the construction the v2 kernel writes into VMEM scratch at
-    ``j == 0`` (exposed for bit-exactness tests against ``dense_block`` /
-    ``dense_global_block`` — the family picks the tile builder).
+    The κ tiles the v2 kernel writes into its (κ, Br, Bc) VMEM scratch at
+    ``j == 0``, side by side (exposed for bit-exactness tests against
+    ``dense_block`` / ``dense_global_block`` — the family picks the tile
+    builder).
     """
     if rows_pattern:
         tile_fn = _phi_rows_tile
@@ -228,151 +257,169 @@ def stacked_phi(plan: BlockPermPlan, g, neighbors, *, rows_pattern: bool = False
 # v2 kernel bodies: fused-κ, single output write, Φ cached across j.
 # ---------------------------------------------------------------------------
 
+def _dot(phi, x, *, transpose: bool = False):
+    """One MXU contraction ``Φ·x`` (``Φᵀ·x`` with ``transpose``) in Φ's
+    compute dtype, accumulated in fp32.  fp32 operands contract at full
+    fp32 precision (``Precision.HIGHEST``); bf16 products are exact in the
+    fp32 accumulator, so bf16/fp8 policies keep the default."""
+    x = x.astype(phi.dtype)
+    prec = (jax.lax.Precision.HIGHEST if phi.dtype == jnp.float32
+            else None)
+    dims = (((0,), (0,)), ((), ())) if transpose else (((1,), (0,)), ((), ()))
+    return jax.lax.dot_general(phi, x, dims, precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
+def _phi_dot(phi_ref, x_chunk, Bc: int, out_shape):
+    """``Φ · x`` for one (B_r, B_c) Φ ref, contracted in lane-aligned
+    chunks of ``phi_chunk(B_c)`` along B_c and summed in chunk order from
+    zero.  ``x_chunk(c0, ch)`` loads rows ``[c0, c0 + ch)`` of the operand.
+    Chunking bounds the contraction's VMEM temporaries at (ch, tn) however
+    wide the block is; every kernel that contracts a Φ tile (fused,
+    gather, partial, v1) goes through here, so their per-ℓ sums are
+    bit-identical."""
+    ch = phi_chunk(Bc)
+
+    def body(c, acc):
+        c0 = pl.multiple_of(c * ch, ch)
+        return acc + _dot(phi_ref[:, pl.ds(c0, ch)], x_chunk(c0, ch))
+
+    return jax.lax.fori_loop(0, Bc // ch, body,
+                             jnp.zeros(out_shape, jnp.float32))
+
+
+def _fused_sum(phi_ref, x_chunk, plan: BlockPermPlan, out_shape):
+    """``Σ_ℓ Φ_ℓ · x_ℓ`` over the κ stacked Φ tiles, summed in ℓ order —
+    the reference oracle's order, and the fold order of the row-sharded
+    partials.  ``x_chunk(ℓ, c0, ch)`` loads rows of the ℓ-th operand.  One
+    (κ·B_c)-deep dot over a concatenated operand would need a (κ·B_c, tn)
+    VMEM copy of the input; κ chunked dots need none."""
+    acc = None
+    for ell in range(plan.kappa):
+        y = _phi_dot(phi_ref.at[ell],
+                     lambda c0, ch, _ell=ell: x_chunk(_ell, c0, ch),
+                     plan.Bc, out_shape)
+        acc = y if acc is None else acc + y
+    return acc
+
+
 def _fused_fwd_kernel(tab_ref, *refs, plan: BlockPermPlan, scale, phi_fn):
     a_refs = refs[: plan.kappa]
     o_ref = refs[plan.kappa]
-    phi_ref = refs[plan.kappa + 1]          # (Br, κ·Bc) VMEM scratch
+    phi_ref = refs[plan.kappa + 1]          # (κ, Br, Bc) VMEM scratch
     g = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
-    def _build_phi():
+    def _build():
         for ell in range(plan.kappa):
-            h = tab_ref[ell, g]
-            phi_ref[:, ell * plan.Bc:(ell + 1) * plan.Bc] = (
-                phi_fn(plan, g, h).astype(phi_ref.dtype)
-            )
+            _build_phi(phi_ref.at[ell], plan, phi_fn, g, tab_ref[ell, g])
 
-    stacked = jnp.concatenate(
-        [a_refs[ell][...] for ell in range(plan.kappa)], axis=0
-    ).astype(phi_ref.dtype)    # (κ·Bc, tn): streamed dtype → MXU compute
-                               # dtype (no-op for fp32/bf16; fp8 upcasts
-                               # to bf16 — exact — inside VMEM)
-    o_ref[...] = jnp.dot(
-        phi_ref[...], stacked, preferred_element_type=jnp.float32
-    ) * scale
+    # streamed dtype → MXU compute dtype inside ``_dot`` (no-op for
+    # fp32/bf16; fp8 upcasts to bf16 — exact — inside VMEM)
+    o_ref[...] = _fused_sum(
+        phi_ref, lambda ell, c0, ch: a_refs[ell][pl.ds(c0, ch), :],
+        plan, o_ref.shape) * scale
 
 
 def _fused_transpose_kernel(tab_ref, *refs, plan: BlockPermPlan, scale,
                             phi_fn):
     y_refs = refs[: plan.kappa]
     o_ref = refs[plan.kappa]
-    phi_ref = refs[plan.kappa + 1]          # (κ·Br, Bc) VMEM scratch
+    phi_ref = refs[plan.kappa + 1]          # (κ, Br, Bc) VMEM scratch
     hb = pl.program_id(0)                   # input-block index (output of Sᵀ)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
-    def _build_phi():
+    def _build():
         for ell in range(plan.kappa):
-            g = tab_ref[ell, hb]            # g = π_{ℓ+1}^{-1}(hb)
-            phi_ref[ell * plan.Br:(ell + 1) * plan.Br, :] = (
-                phi_fn(plan, g, hb).astype(phi_ref.dtype)
-            )
+            # g = π_{ℓ+1}^{-1}(hb)
+            _build_phi(phi_ref.at[ell], plan, phi_fn, tab_ref[ell, hb], hb)
 
-    stacked = jnp.concatenate(
-        [y_refs[ell][...] for ell in range(plan.kappa)], axis=0
-    ).astype(phi_ref.dtype)                  # (κ·Br, tn), MXU compute dtype
-    o_ref[...] = jnp.dot(
-        phi_ref[...].T, stacked, preferred_element_type=jnp.float32
-    ) * scale
+    # X_hb = Σ_ℓ Φ_ℓᵀ · Y_ℓ, written in (ch, tn) row chunks of the output
+    # (the contraction depth is B_r; chunking the B_c output rows keeps
+    # the transposed-Φ temporaries small)
+    ch = phi_chunk(plan.Bc)
+
+    def body(c, carry):
+        c0 = pl.multiple_of(c * ch, ch)
+        acc = None
+        for ell in range(plan.kappa):
+            x = _dot(phi_ref[ell, :, pl.ds(c0, ch)], y_refs[ell][...],
+                     transpose=True)
+            acc = x if acc is None else acc + x
+        o_ref[pl.ds(c0, ch), :] = acc * scale
+        return carry
+
+    jax.lax.fori_loop(0, plan.Bc // ch, body, 0)
 
 
 def _fused_gather_kernel(tab_ref, rmap_ref, a_any, o_ref, gat_ref, phi_ref,
-                         sem, *, plan: BlockPermPlan, scale, phi_fn, tn: int,
-                         n_rem: int = 0):
+                         sem, *, plan: BlockPermPlan, scale, phi_fn, tn: int):
     """Gather-fused fwd/blockrow body: Y[g, j] = Φ* · A[rmap[blocks], j·tn:].
 
     The operand ``a_any`` is the FULL source matrix left in HBM
-    (``memory_space=ANY``); ``rmap_ref`` is the scalar-prefetched per-row
-    index map of the *masked* input (length d_pad, padding entries pointing
-    at a caller-appended zero row).  Each program DMAs its κ·B_c gathered
-    rows into ``gat_ref`` (VMEM) row by row — the TPU analogue of the
-    coalesced index-streamed gather — then reuses the v2 single-write
-    contraction against the Φ scratch cached across column tiles.
-
-    ``n_rem`` is the ragged column remainder ``n % tn`` of the UNPADDED
-    source: when nonzero, the last column tile DMAs only the ``n_rem``
-    valid columns per row (the source is never padded — padding A would
-    materialize a full HBM copy, exactly what this path exists to avoid)
-    and zero-fills the scratch tail so the contraction still sees a full
-    (κ·B_c, tn) tile.
+    (``memory_space=ANY``) as the ``(d_src, 1, n_pad)`` fp32 row stack of
+    ``_row_stack``: the chip lays a 2-D array out in (8, 128) tiles, from
+    which its DMA engine cannot cut a single row, while a unit
+    second-minor dimension is laid out one row per (1, 128) tile; and the
+    row stack's columns are padded to the tile grid, so every row DMA
+    moves a whole lane-aligned tile.  ``rmap_ref`` is the scalar-prefetched
+    per-row index map of the *masked* input (length d_pad, padding entries
+    pointing at any valid row).  Each program DMAs its κ·B_c gathered rows
+    into ``gat_ref`` (VMEM, ``(κ·B_c, 1, tn)``) row by row — the TPU
+    analogue of the coalesced index-streamed gather — then runs the v2
+    single-write contraction against the Φ scratch cached across column
+    tiles, term for term as the non-gather kernel does (bit-exact to it on
+    a materialized gather).
     """
     g = pl.program_id(0)
     j = pl.program_id(1)
-    last_j = pl.num_programs(1) - 1
+    # a lone column tile is taken whole: no (dynamic) window offset for
+    # the compiler to prove aligned
+    cols = (slice(None) if a_any.shape[2] == tn
+            else pl.ds(pl.multiple_of(j * tn, tn), tn))
 
     @pl.when(j == 0)
-    def _build_phi():
+    def _build():
         for ell in range(plan.kappa):
-            h = tab_ref[ell, g]
-            phi_ref[:, ell * plan.Bc:(ell + 1) * plan.Bc] = (
-                phi_fn(plan, g, h).astype(phi_ref.dtype)
-            )
+            _build_phi(phi_ref.at[ell], plan, phi_fn, g, tab_ref[ell, g])
 
-    def _row_dma(ell, h, r, width):
-        src = rmap_ref[h * plan.Bc + r]
+    def _row_dma(ell, h, r):
         return pltpu.make_async_copy(
-            a_any.at[src, pl.ds(j * tn, width)],
-            gat_ref.at[ell * plan.Bc + r, pl.ds(0, width)],
-            sem,
-        )
+            a_any.at[rmap_ref[h * plan.Bc + r], :, cols],
+            gat_ref.at[ell * plan.Bc + r], sem)
 
-    def _gather_rows(width):
-        # Issue every row copy before waiting on any: the destinations are
-        # disjoint scratch rows and the DMA semaphore counts completions, so
-        # up to κ·B_c transfers are in flight at once instead of paying κ·B_c
-        # serialized HBM round-trips per program.
-        for ell in range(plan.kappa):
-            h = tab_ref[ell, g]
-            jax.lax.fori_loop(
-                0, plan.Bc,
-                lambda r, _, _ell=ell, _h=h: (
-                    _row_dma(_ell, _h, r, width).start(), 0)[1],
-                0)
-        for ell in range(plan.kappa):
-            h = tab_ref[ell, g]
-            jax.lax.fori_loop(
-                0, plan.Bc,
-                lambda r, _, _ell=ell, _h=h: (
-                    _row_dma(_ell, _h, r, width).wait(), 0)[1],
-                0)
+    # Issue every row copy before waiting on any: the destinations are
+    # disjoint scratch rows and the DMA semaphore counts completions, so
+    # up to κ·B_c transfers are in flight at once instead of paying κ·B_c
+    # serialized HBM round-trips per program.
+    for ell in range(plan.kappa):
+        h = tab_ref[ell, g]
+        jax.lax.fori_loop(
+            0, plan.Bc,
+            lambda r, _, _ell=ell, _h=h: (_row_dma(_ell, _h, r).start(), 0)[1],
+            0)
+    for ell in range(plan.kappa):
+        h = tab_ref[ell, g]
+        jax.lax.fori_loop(
+            0, plan.Bc,
+            lambda r, _, _ell=ell, _h=h: (_row_dma(_ell, _h, r).wait(), 0)[1],
+            0)
 
-    if n_rem:
-        if a_any.shape[1] >= tn:
-            # only trace the full-width branch when full tiles exist — a
-            # tn-wide slice of a narrower-than-tn operand is invalid even
-            # inside a never-taken pl.when
-            @pl.when(j != last_j)
-            def _full_tile():
-                _gather_rows(tn)
-
-        @pl.when(j == last_j)
-        def _ragged_tile():
-            _gather_rows(n_rem)
-            # scratch persists across grid steps: columns ≥ n_rem hold the
-            # previous tile's data and must be zeroed, making the ragged
-            # tail bit-identical to a zero-padded materialized gather
-            gat_ref[:, n_rem:] = jnp.zeros_like(gat_ref[:, n_rem:])
-    else:
-        _gather_rows(tn)
-
-    if plan.d < plan.d_pad:
+    def _operand(ell, c0, ch):
+        blk = gat_ref[pl.ds(ell * plan.Bc + c0, ch), :, :].reshape(ch, tn)
+        if plan.d == plan.d_pad:
+            return blk
         # Padded masked rows (global index ≥ plan.d) gathered a placeholder
         # source row; zero them here so padding contributes exact zeros —
         # bit-identical to zero-padding a materialized gather, without ever
         # copying A to append a zero row.
-        for ell in range(plan.kappa):
-            h = tab_ref[ell, g]
-            rows = h * plan.Bc + jax.lax.broadcasted_iota(
-                jnp.int32, (plan.Bc, 1), 0)
-            blk = gat_ref[ell * plan.Bc:(ell + 1) * plan.Bc, :]
-            gat_ref[ell * plan.Bc:(ell + 1) * plan.Bc, :] = jnp.where(
-                rows < plan.d, blk, jnp.zeros_like(blk))
+        rows = tab_ref[ell, g] * plan.Bc + c0 + jax.lax.broadcasted_iota(
+            jnp.int32, (ch, 1), 0)
+        return jnp.where(rows < plan.d, blk, jnp.zeros_like(blk))
 
-    o_ref[...] = jnp.dot(
-        phi_ref[...], gat_ref[...].astype(phi_ref.dtype),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    o_ref[...] = _fused_sum(phi_ref, _operand, plan, o_ref.shape) * scale
 
 
 def _partial_fwd_kernel(tab_ref, a_ref, o_ref, phi_ref, *,
@@ -408,15 +455,12 @@ def _partial_fwd_kernel(tab_ref, a_ref, o_ref, phi_ref, *,
     j = pl.program_id(2)
 
     @pl.when(j == 0)
-    def _build_phi():
-        g = tab_ref[0, ell, m]
-        h = tab_ref[1, ell, m]
-        phi_ref[...] = phi_fn(plan, g, h).astype(phi_ref.dtype)
+    def _build():
+        _build_phi(phi_ref, plan, phi_fn, tab_ref[0, ell, m],
+                   tab_ref[1, ell, m])
 
-    o_ref[0] = jnp.dot(
-        phi_ref[...], a_ref[...].astype(phi_ref.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    o_ref[0] = _phi_dot(phi_ref, lambda c0, ch: a_ref[pl.ds(c0, ch), :],
+                        plan.Bc, o_ref.shape[1:])
 
 
 def _partial_masked_kernel(tab_ref, a_ref, o_ref, phi_ref, *,
@@ -438,80 +482,109 @@ def _partial_masked_kernel(tab_ref, a_ref, o_ref, phi_ref, *,
     owned = tab_ref[2, ell, g]
 
     @pl.when((j == 0) & (owned == 1))
-    def _build_phi():
-        h = tab_ref[1, ell, g]
-        phi_ref[...] = phi_fn(plan, g, h).astype(phi_ref.dtype)
+    def _build():
+        _build_phi(phi_ref, plan, phi_fn, g, tab_ref[1, ell, g])
 
     @pl.when((j == 0) & (owned == 0))
     def _zero_phi():
         # non-owned pairs still skip the s hash passes
         phi_ref[...] = jnp.zeros_like(phi_ref)
 
-    o_ref[0] = jnp.dot(
-        phi_ref[...], a_ref[...].astype(phi_ref.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    o_ref[0] = _phi_dot(phi_ref, lambda c0, ch: a_ref[pl.ds(c0, ch), :],
+                        plan.Bc, o_ref.shape[1:])
 
 
 # ---------------------------------------------------------------------------
 # pallas_call wrappers (raw; user-facing API with padding/custom_vjp in ops.py)
 # ---------------------------------------------------------------------------
 
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _compiler_params(semantics):
+    """Mosaic parameters of every launch here: the grid's dimension
+    semantics, and the scoped-VMEM limit the engine budgets its tiles
+    against (``blockperm.VMEM_BUDGET_BYTES`` — one number for both)."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_BUDGET_BYTES)
 
 
-def _compiler_params(interpret: bool, semantics):
-    if interpret:
-        return None
-    try:
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except AttributeError:  # older jax spelling
-        return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+def _k_view(plan: BlockPermPlan, x: jnp.ndarray) -> jnp.ndarray:
+    """The ``(M, B_r, n)`` view of a k-side array (S's output, Sᵀ's
+    input).  The chip takes a block's second-minor dimension only as a
+    multiple of 8 or the array's full extent, and B_r can be as small as
+    s = 2; in this view every ``(None, B_r, tn)`` block spans the full
+    second-minor extent.  A layout-preserving reshape whenever B_r fills
+    whole sublane tiles."""
+    return x.reshape(plan.M, plan.Br, x.shape[-1])
 
 
-def _run_v1(plan, kernel, tab, operand, in_block, out_block, out_rows, n, tn,
-            interpret):
+def _d_spec(plan: BlockPermPlan, tn: int, index):
+    """(B_c, tn) block of a d-side array (S's input, Sᵀ's output);
+    ``index(*program_ids)`` gives its (row block, column tile)."""
+    return pl.BlockSpec((plan.Bc, tn), index)
+
+
+def _k_spec(plan: BlockPermPlan, tn: int, index):
+    """(B_r, tn) block of a k-side array's ``_k_view``; ``index`` as in
+    ``_d_spec``."""
+    def k_index(*ids):
+        r, j = index(*ids)
+        return r, 0, j
+    return pl.BlockSpec((None, plan.Br, tn), k_index)
+
+
+def _io_specs(transpose: bool):
+    """(input, output) block-spec makers of a forward or transpose launch."""
+    return (_k_spec, _d_spec) if transpose else (_d_spec, _k_spec)
+
+
+def _run_v1(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
     """v1 launcher.  ``n`` may be ragged (``n % tn != 0``): the grid covers
     ⌈n/tn⌉ column tiles and the edge tile is handled by the Pallas
     machinery itself (masked loads/stores on TPU, internal pad+slice in
     interpret mode).  The contraction is column-local, so edge-tile
     garbage never leaks into valid columns — the operand is NEVER padded
-    at trace level (no HBM copy of A just to round n up)."""
+    at trace level (no HBM copy of A just to round n up).  Returns the
+    (k_pad, n) forward or, with ``transpose``, the (d_pad, n) result."""
     grid = (-(-n // tn), plan.M, plan.kappa)
+    in_spec, out_spec = _io_specs(transpose)
+    if transpose:
+        operand = _k_view(plan, operand)
+        out_shape = (plan.d_pad, n)
+    else:
+        out_shape = (plan.M, plan.Br, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(in_block, lambda j, g, l, tab_ref: (tab_ref[l, g], j)),
-        ],
-        out_specs=pl.BlockSpec(out_block, lambda j, g, l, tab_ref: (g, j)),
+        in_specs=[in_spec(plan, tn,
+                          lambda j, g, l, tab_ref: (tab_ref[l, g], j))],
+        out_specs=out_spec(plan, tn, lambda j, g, l, tab_ref: (g, j)),
+        scratch_shapes=[pltpu.VMEM((plan.Br, plan.Bc), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
-        compiler_params=_compiler_params(
-            interpret, ("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
     )(jnp.asarray(tab), operand)
+    return out if transpose else out.reshape(plan.k_pad, n)
 
 
-def _run_fused(plan, kernel, tab, operand, in_block, out_block, phi_shape,
-               out_rows, n, tn, interpret):
+def _run_fused(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
     """v2 launcher: grid (M, n/tn), κ pipelined views of one operand, Φ scratch.
 
     The same operand is passed κ times — each view has its own BlockSpec whose
     index_map picks input block ``tab[ℓ, ·]``, so the pipeline prefetches all
     κ gathered blocks for program (g, j) without any HBM-side gather copy.
+    The Φ scratch holds the κ (B_r, B_c) tiles of the program's block row
+    (forward) or block column (transpose) as a ``(κ, B_r, B_c)`` stack.
 
     ``n`` may be ragged (``n % tn != 0``): the grid covers ⌈n/tn⌉ column
     tiles and the edge tile rides the Pallas machinery (masked loads/stores
     on TPU, internal pad+slice in interpret mode).  Output columns of the
     edge tile beyond ``n`` are garbage but are dropped by the machinery;
     the contraction is column-local so valid columns are untouched.  The
-    operand is NEVER column-padded at trace level.
+    operand is NEVER column-padded at trace level.  Returns the (k_pad, n)
+    forward or, with ``transpose``, the (d_pad, n) result.
     """
     grid = (plan.M, -(-n // tn))
     # Φ scratch lives in the MXU compute dtype: identical to the streamed
@@ -522,60 +595,65 @@ def _run_fused(plan, kernel, tab, operand, in_block, out_block, phi_shape,
     def _gather_map(ell):
         return lambda g, j, tab_ref: (tab_ref[ell, g], j)
 
+    in_spec, out_spec = _io_specs(transpose)
+    if transpose:
+        operand = _k_view(plan, operand)
+        out_shape = (plan.d_pad, n)
+    else:
+        out_shape = (plan.M, plan.Br, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(in_block, _gather_map(ell)) for ell in range(plan.kappa)
-        ],
-        out_specs=pl.BlockSpec(out_block, lambda g, j, tab_ref: (g, j)),
-        scratch_shapes=[pltpu.VMEM(phi_shape, cdt)],
+        in_specs=[in_spec(plan, tn, _gather_map(ell))
+                  for ell in range(plan.kappa)],
+        out_specs=out_spec(plan, tn, lambda g, j, tab_ref: (g, j)),
+        scratch_shapes=[pltpu.VMEM((plan.kappa, plan.Br, plan.Bc), cdt)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
         # j must run sequentially per g (Φ scratch is built at j == 0);
         # g tiles are independent and may be megacore-partitioned.
-        compiler_params=_compiler_params(interpret, ("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary")),
     )(jnp.asarray(tab), *([operand] * plan.kappa))
+    return out if transpose else out.reshape(plan.k_pad, n)
 
 
-def _run_fused_gather(plan, kernel, tab, row_map, operand, out_block,
-                      out_rows, n, tn, interpret):
-    """Gather launcher: grid (M, ⌈n/tn⌉); operand stays in HBM (ANY memory
-    space), masked rows arrive via in-kernel DMA driven by the
-    scalar-prefetched ``row_map``; Φ scratch is cached across j as in v2.
-
-    ``n`` may be ragged (``n % tn != 0``): only the OUTPUT is padded to the
-    tile grid — the kernel clips the last tile's row DMAs to the valid
-    width, so the HBM source is never copied/padded.
-    """
-    n_pad = ((n + tn - 1) // tn) * tn
+def _run_fused_gather(plan, kernel, tab, row_map, rows, tn, interpret):
+    """Gather launcher: grid (M, n_pad/tn) over the ``_row_stack`` operand
+    ``rows`` (``(d_src, 1, n_pad)``, left in HBM — ANY memory space);
+    masked rows arrive via in-kernel DMA driven by the scalar-prefetched
+    ``row_map``; Φ scratch is cached across j as in v2.  Returns the
+    ``(k_pad, n_pad)`` output; the caller slices off the padded
+    columns (exact zeros)."""
+    n_pad = rows.shape[2]
     grid = (plan.M, n_pad // tn)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(out_block, lambda g, j, tab_ref, rmap_ref: (g, j)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=_k_spec(plan, tn,
+                          lambda g, j, tab_ref, rmap_ref: (g, j)),
         scratch_shapes=[
-            # gather scratch holds the raw DMA'd rows — streamed dtype;
-            # Φ scratch holds the MXU compute dtype (gat tiles upcast to
-            # it at the contraction; identical dtypes except under fp8)
-            pltpu.VMEM((plan.kappa * plan.Bc, tn), operand.dtype),
-            pltpu.VMEM((out_block[0], plan.kappa * plan.Bc),
+            # gather scratch holds the raw DMA'd fp32 rows (``_row_stack``);
+            # Φ scratch holds the MXU compute dtype (gathered chunks are
+            # cast to it at the contraction)
+            pltpu.VMEM((plan.kappa * plan.Bc, 1, tn), rows.dtype),
+            pltpu.VMEM((plan.kappa, plan.Br, plan.Bc),
                        plan.precision.compute_dtype),      # Φ*
             pltpu.SemaphoreType.DMA(()),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, n_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((plan.M, plan.Br, n_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=_compiler_params(interpret, ("parallel", "arbitrary")),
-    )(jnp.asarray(tab), jnp.asarray(row_map, jnp.int32), operand)
+        compiler_params=_compiler_params(("parallel", "arbitrary")),
+    )(jnp.asarray(tab), jnp.asarray(row_map, jnp.int32), rows)
+    return out.reshape(plan.k_pad, n_pad)
 
 
 def _stream(plan: BlockPermPlan, operand: jnp.ndarray) -> jnp.ndarray:
@@ -591,29 +669,41 @@ def _stream(plan: BlockPermPlan, operand: jnp.ndarray) -> jnp.ndarray:
         operand, plan.precision, seed=plan.seed)
 
 
+def _row_stack(plan: BlockPermPlan, operand: jnp.ndarray,
+               tn: int) -> jnp.ndarray:
+    """The gather kernels' source: the stream-quantized operand as a
+    ``(d, 1, n_pad)`` fp32 stack of rows, columns zero-padded to a whole
+    number of ``tn`` tiles — the HBM layout they DMA single rows from (see
+    ``_fused_gather_kernel``).  The chip packs 2 (bf16) or 4 (fp8) rows
+    into one tile row, so sub-32-bit streams are gathered as their
+    exactly-representable fp32 values: the policy's rounding holds bit for
+    bit, its HBM saving does not.  One XLA fusion writes the stack (with
+    the batched fold when there is one): the fused gather makes one pass
+    over A and never materializes the gathered ``A[row_map]``."""
+    q = _stream(plan, operand).astype(jnp.float32)
+    d, n = q.shape
+    q = q.reshape(d, 1, n)
+    n_pad = -(-n // tn) * tn
+    return q if n_pad == n else jnp.pad(q, ((0, 0), (0, 0), (0, n_pad - n)))
+
+
 def flashsketch_pallas(
     plan: BlockPermPlan,
     A: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Y = S A via the fused v2 kernel. A must be (d_pad, n); n may be
     ragged (the ⌈n/tn⌉ edge tile is handled by the Pallas machinery)."""
-    if interpret is None:
-        interpret = _should_interpret()
     d_pad, n = A.shape
     assert d_pad == plan.d_pad, (d_pad, plan.d_pad)
     phi_fn, tab = _fwd_phi_and_table(plan)
     kernel = functools.partial(
         _fused_fwd_kernel, plan=plan, scale=plan.scale, phi_fn=phi_fn
     )
-    return _run_fused(
-        plan, kernel, tab, _stream(plan, A),
-        in_block=(plan.Bc, tn), out_block=(plan.Br, tn),
-        phi_shape=(plan.Br, plan.kappa * plan.Bc),
-        out_rows=plan.k_pad, n=n, tn=tn, interpret=interpret,
-    )
+    return _run_fused(plan, kernel, tab, _stream(plan, A), n=n, tn=tn,
+                      interpret=interpret)
 
 
 def flashsketch_transpose_pallas(
@@ -621,22 +711,16 @@ def flashsketch_transpose_pallas(
     Y: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """X = Sᵀ Y via the fused v2 kernel. Y must be (k_pad, n); ragged n ok."""
-    if interpret is None:
-        interpret = _should_interpret()
     k_pad, n = Y.shape
     assert k_pad == plan.k_pad, (k_pad, plan.k_pad)
     phi_fn, tab = _transpose_phi_and_table(plan)
     kernel = functools.partial(_fused_transpose_kernel, plan=plan,
                                scale=plan.scale, phi_fn=phi_fn)
-    return _run_fused(
-        plan, kernel, tab, _stream(plan, Y),
-        in_block=(plan.Br, tn), out_block=(plan.Bc, tn),
-        phi_shape=(plan.kappa * plan.Br, plan.Bc),
-        out_rows=plan.d_pad, n=n, tn=tn, interpret=interpret,
-    )
+    return _run_fused(plan, kernel, tab, _stream(plan, Y), n=n, tn=tn,
+                      interpret=interpret, transpose=True)
 
 
 def flashsketch_pallas_gather(
@@ -645,38 +729,34 @@ def flashsketch_pallas_gather(
     row_map: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Y = S · A[row_map, :] in ONE launch — the gather-fused v2 kernel.
 
     Args:
       plan: frozen plan for the *masked* input dim (``plan.d`` = rows kept).
-      A: ``(d_src, n)`` source matrix; ``n`` may be ragged (``n % tn != 0``
-        — the kernel handles the last tile in-kernel, A is NEVER padded or
-        copied).  Stays in HBM; the kernel DMAs only the masked rows.
+      A: ``(d_src, n)`` source matrix, any ``n``.  Rewritten once as the
+        kernel's row stack (``_row_stack``); the kernel DMAs only the
+        masked rows of it.
       row_map: ``(d_pad,)`` int32 — source row of A feeding each padded
         masked row.  Entries beyond ``plan.d`` may point at any valid row
-        (``lowering.row_map_for`` uses 0); the kernel zeroes those gather-
-        scratch rows before the contraction.
+        (``lowering.row_map_for`` uses 0); the kernel zeroes those
+        contributions before the contraction.
+      tn: column-tile width, a multiple of 128 (the row DMAs move whole
+        lane tiles).
 
     Returns:
       ``(k_pad, ⌈n/tn⌉·tn)`` fp32 — the caller slices off the padded
       output columns (they are exact zeros).
     """
-    if interpret is None:
-        interpret = _should_interpret()
-    _, n = A.shape
     assert row_map.shape == (plan.d_pad,), (row_map.shape, plan.d_pad)
     phi_fn, tab = _fwd_phi_and_table(plan)
     kernel = functools.partial(
         _fused_gather_kernel, plan=plan, scale=plan.scale, phi_fn=phi_fn,
-        tn=tn, n_rem=n % tn,
-    )
-    return _run_fused_gather(
-        plan, kernel, tab, row_map, _stream(plan, A),
-        out_block=(plan.Br, tn), out_rows=plan.k_pad, n=n, tn=tn,
-        interpret=interpret,
-    )
+        tn=tn)
+    return _run_fused_gather(plan, kernel, tab, row_map,
+                             _row_stack(plan, A, tn), tn=tn,
+                             interpret=interpret)
 
 
 def blockrow_pallas_gather(
@@ -685,27 +765,20 @@ def blockrow_pallas_gather(
     row_map: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """FLASHBLOCKROW over gathered rows: Y = S_row · A[row_map, :], fused.
 
-    Ragged ``n`` handled in-kernel like ``flashsketch_pallas_gather`` —
-    only the output is tile-padded, never the HBM source.
+    Same operand contract as ``flashsketch_pallas_gather``.
     """
-    if interpret is None:
-        interpret = _should_interpret()
-    _, n = A.shape
     assert row_map.shape == (plan.d_pad,), (row_map.shape, plan.d_pad)
     scale = plan.scale * math.sqrt(plan.d_pad / plan.k_pad)
     kernel = functools.partial(
         _fused_gather_kernel, plan=plan, scale=scale, phi_fn=_phi_rows_tile,
-        tn=tn, n_rem=n % tn,
-    )
-    return _run_fused_gather(
-        plan, kernel, _blockrow_table(plan), row_map, _stream(plan, A),
-        out_block=(plan.Br, tn), out_rows=plan.k_pad, n=n, tn=tn,
-        interpret=interpret,
-    )
+        tn=tn)
+    return _run_fused_gather(plan, kernel, _blockrow_table(plan), row_map,
+                             _row_stack(plan, A, tn), tn=tn,
+                             interpret=interpret)
 
 
 def blockrow_pallas(
@@ -713,11 +786,9 @@ def blockrow_pallas(
     A: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """FLASHBLOCKROW forward via the fused v2 kernel. A: (d_pad, n); ragged n ok."""
-    if interpret is None:
-        interpret = _should_interpret()
     d_pad, n = A.shape
     assert d_pad == plan.d_pad
     h_np = _blockrow_table(plan)                            # (κ, M) static
@@ -725,12 +796,8 @@ def blockrow_pallas(
     kernel = functools.partial(
         _fused_fwd_kernel, plan=plan, scale=scale, phi_fn=_phi_rows_tile
     )
-    return _run_fused(
-        plan, kernel, h_np, _stream(plan, A),
-        in_block=(plan.Bc, tn), out_block=(plan.Br, tn),
-        phi_shape=(plan.Br, plan.kappa * plan.Bc),
-        out_rows=plan.k_pad, n=n, tn=tn, interpret=interpret,
-    )
+    return _run_fused(plan, kernel, h_np, _stream(plan, A), n=n, tn=tn,
+                      interpret=interpret)
 
 
 def flashsketch_pallas_partial(
@@ -740,7 +807,7 @@ def flashsketch_pallas_partial(
     *,
     tn: int = 128,
     rows_pattern: bool = False,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Per-ℓ UNSCALED partial sketch of a contiguous block slab.
 
@@ -769,8 +836,6 @@ def flashsketch_pallas_partial(
       over the shard axis then an ℓ-ordered fold recovers the full
       ``S·A / scale`` bit-exactly (one nonzero contributor per element).
     """
-    if interpret is None:
-        interpret = _should_interpret()
     rows_loc, n = A_local.shape
     assert rows_loc % plan.Bc == 0, (rows_loc, plan.Bc)
     M_loc = rows_loc // plan.Bc
@@ -784,8 +849,8 @@ def flashsketch_pallas_partial(
         grid = (plan.M, plan.kappa, n_tiles)
         in_spec = pl.BlockSpec(
             (plan.Bc, tn), lambda g, l, j, tab_ref: (tab_ref[0, l, g], j))
-        out_rows = plan.k_pad
-        out_map = lambda g, l, j, tab_ref: (l, g, j)       # noqa: E731
+        out_blocks = plan.M
+        out_map = lambda g, l, j, tab_ref: (l, g, 0, j)    # noqa: E731
     else:
         assert tables.shape == (2, plan.kappa, M_loc), tables.shape
         kernel = functools.partial(
@@ -793,44 +858,46 @@ def flashsketch_pallas_partial(
         grid = (M_loc, plan.kappa, n_tiles)
         in_spec = pl.BlockSpec(
             (plan.Bc, tn), lambda m, l, j, tab_ref: (m, j))
-        out_rows = M_loc * plan.Br
-        out_map = lambda m, l, j, tab_ref: (l, m, j)       # noqa: E731
+        out_blocks = M_loc
+        out_map = lambda m, l, j, tab_ref: (l, m, 0, j)    # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[in_spec],
-        out_specs=pl.BlockSpec((1, plan.Br, tn), out_map),
+        # each output block its own leading entry (``_k_view``)
+        out_specs=pl.BlockSpec((1, None, plan.Br, tn), out_map),
         scratch_shapes=[pltpu.VMEM((plan.Br, plan.Bc),
                                    plan.precision.compute_dtype)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (plan.kappa, out_rows, n), jnp.float32),
+            (plan.kappa, out_blocks, plan.Br, n), jnp.float32),
         interpret=interpret,
         # j must run sequentially per (block, ℓ) — the Φ scratch is built
         # at j == 0; block tiles are independent.
         compiler_params=_compiler_params(
-            interpret, ("parallel", "arbitrary", "arbitrary")),
+            ("parallel", "arbitrary", "arbitrary")),
     )(jnp.asarray(tables, jnp.int32), operand)
+    return out.reshape(plan.kappa, out_blocks * plan.Br, n)
 
 
 # ---------------------------------------------------------------------------
 # v1 kernels — output-revisiting grid reduction.  Reference oracle for the
-# equivalence tests and the baseline for kernel_bench; always fp32.
+# equivalence tests, the baseline for kernel_bench, and the engine's rung
+# for plans whose stacked Φ cannot fit VMEM; always fp32.
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel_v1(tab_ref, a_ref, o_ref, *, plan: BlockPermPlan, scale,
-                   phi_fn=_phi_tile):
+def _fwd_kernel_v1(tab_ref, a_ref, o_ref, phi_ref, *, plan: BlockPermPlan,
+                   scale, phi_fn):
+    """Forward (and FLASHBLOCKROW, via ``phi_fn``) v1 body: one (g, ℓ) pair
+    per program, Φ rebuilt into its (B_r, B_c) scratch every program."""
     g = pl.program_id(1)
     ell = pl.program_id(2)
-    h = tab_ref[ell, g]
-    phi = phi_fn(plan, g, h)
-    contrib = jnp.dot(
-        phi, a_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    _build_phi(phi_ref, plan, phi_fn, g, tab_ref[ell, g])
+    contrib = _phi_dot(phi_ref, lambda c0, ch: a_ref[pl.ds(c0, ch), :],
+                       plan.Bc, o_ref.shape) * scale
 
     @pl.when(ell == 0)
     def _init():
@@ -841,43 +908,29 @@ def _fwd_kernel_v1(tab_ref, a_ref, o_ref, *, plan: BlockPermPlan, scale,
         o_ref[...] += contrib
 
 
-def _transpose_kernel_v1(tab_ref, y_ref, o_ref, *, plan: BlockPermPlan,
-                         scale, phi_fn=_phi_tile):
+def _transpose_kernel_v1(tab_ref, y_ref, o_ref, phi_ref, *,
+                         plan: BlockPermPlan, scale, phi_fn):
     hb = pl.program_id(1)               # input block index (output of Sᵀ)
     ell = pl.program_id(2)
-    g = tab_ref[ell, hb]                # g = f^{-ℓ}(hb)
-    phi = phi_fn(plan, g, hb)           # (Br, Bc)
-    contrib = jnp.dot(
-        phi.T, y_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    _build_phi(phi_ref, plan, phi_fn, tab_ref[ell, hb], hb)  # g = f^{-ℓ}(hb)
+    ch = phi_chunk(plan.Bc)
 
-    @pl.when(ell == 0)
-    def _init():
-        o_ref[...] = contrib
+    def body(c, carry):
+        c0 = pl.multiple_of(c * ch, ch)
+        contrib = _dot(phi_ref[:, pl.ds(c0, ch)], y_ref[...],
+                       transpose=True) * scale
 
-    @pl.when(ell > 0)
-    def _acc():
-        o_ref[...] += contrib
+        @pl.when(ell == 0)
+        def _init():
+            o_ref[pl.ds(c0, ch), :] = contrib
 
+        @pl.when(ell > 0)
+        def _acc():
+            o_ref[pl.ds(c0, ch), :] += contrib
 
-def _blockrow_kernel_v1(tab_ref, a_ref, o_ref, *, plan: BlockPermPlan, scale):
-    g = pl.program_id(1)
-    ell = pl.program_id(2)
-    h = tab_ref[ell, g]
-    phi = _phi_rows_tile(plan, g, h)
-    contrib = jnp.dot(
-        phi, a_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    ) * scale
+        return carry
 
-    @pl.when(ell == 0)
-    def _init():
-        o_ref[...] = contrib
-
-    @pl.when(ell > 0)
-    def _acc():
-        o_ref[...] += contrib
+    jax.lax.fori_loop(0, plan.Bc // ch, body, 0)
 
 
 def flashsketch_pallas_v1(
@@ -885,21 +938,15 @@ def flashsketch_pallas_v1(
     A: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Y = S A via the v1 grid-reduction kernel (fp32 only; ragged n ok)."""
-    if interpret is None:
-        interpret = _should_interpret()
     d_pad, n = A.shape
     assert d_pad == plan.d_pad, (d_pad, plan.d_pad)
     phi_fn, tab = _fwd_phi_and_table(plan)
     kernel = functools.partial(_fwd_kernel_v1, plan=plan, scale=plan.scale,
                                phi_fn=phi_fn)
-    return _run_v1(
-        plan, kernel, tab, A,
-        in_block=(plan.Bc, tn), out_block=(plan.Br, tn),
-        out_rows=plan.k_pad, n=n, tn=tn, interpret=interpret,
-    )
+    return _run_v1(plan, kernel, tab, A, n=n, tn=tn, interpret=interpret)
 
 
 def flashsketch_transpose_pallas_v1(
@@ -907,21 +954,16 @@ def flashsketch_transpose_pallas_v1(
     Y: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """X = Sᵀ Y via the v1 grid-reduction kernel (fp32 only; ragged n ok)."""
-    if interpret is None:
-        interpret = _should_interpret()
     k_pad, n = Y.shape
     assert k_pad == plan.k_pad, (k_pad, plan.k_pad)
     phi_fn, tab = _transpose_phi_and_table(plan)
     kernel = functools.partial(_transpose_kernel_v1, plan=plan,
                                scale=plan.scale, phi_fn=phi_fn)
-    return _run_v1(
-        plan, kernel, tab, Y,
-        in_block=(plan.Br, tn), out_block=(plan.Bc, tn),
-        out_rows=plan.d_pad, n=n, tn=tn, interpret=interpret,
-    )
+    return _run_v1(plan, kernel, tab, Y, n=n, tn=tn, interpret=interpret,
+                   transpose=True)
 
 
 def blockrow_pallas_v1(
@@ -929,19 +971,14 @@ def blockrow_pallas_v1(
     A: jnp.ndarray,
     *,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """FLASHBLOCKROW forward via the v1 grid-reduction kernel (fp32 only;
     ragged n ok)."""
-    if interpret is None:
-        interpret = _should_interpret()
     d_pad, n = A.shape
     assert d_pad == plan.d_pad
     h_np = _blockrow_table(plan)                            # (κ, M) static
     scale = plan.scale * math.sqrt(plan.d_pad / plan.k_pad)
-    kernel = functools.partial(_blockrow_kernel_v1, plan=plan, scale=scale)
-    return _run_v1(
-        plan, kernel, h_np, A,
-        in_block=(plan.Bc, tn), out_block=(plan.Br, tn),
-        out_rows=plan.k_pad, n=n, tn=tn, interpret=interpret,
-    )
+    kernel = functools.partial(_fwd_kernel_v1, plan=plan, scale=scale,
+                               phi_fn=_phi_rows_tile)
+    return _run_v1(plan, kernel, h_np, A, n=n, tn=tn, interpret=interpret)

@@ -48,8 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import precision as precision_mod
-from repro.core.blockperm import (MIN_TILE_N, VMEM_BUDGET_BYTES,
-                                  BlockPermPlan, fused_variant_bytes)
+from repro.core.blockperm import (VMEM_BUDGET_BYTES, BlockPermPlan,
+                                  fused_variant_bytes)
 from repro.health import report as health_report
 from repro.kernels import flashsketch as fsk
 from repro.kernels import ref as kref
@@ -120,10 +120,14 @@ class Lowering:
         ``n_loc``/``batch_loc``/``n_eff = n_loc·batch_loc`` that the
         kernel (and the cost model) actually sees.
       * footprint — ``vmem_bytes`` of the launched kernel's working set
-        (``None`` for xla); ``pad_rows`` = zero rows added to the operand
+        (``None`` for xla), which the chip's compiler must not exceed;
+        ``pad_rows`` = zero rows added to the operand
         before launch, ``pad_cols`` = columns padded in HBM — ALWAYS 0:
         ragged column tails are handled in-kernel (masked edge tiles /
         clipped gather DMA), never by copying the operand.
+      * ``interpret`` — the Pallas kernel runs in interpret mode.  Set
+        only for a Pallas impl off the TPU backend, and shown by
+        ``describe()``: on a TPU every Pallas lowering compiles.
     """
 
     plan: BlockPermPlan
@@ -147,6 +151,7 @@ class Lowering:
     vmem_bytes: Optional[int]
     pad_rows: int
     pad_cols: int
+    interpret: bool = False
 
     @property
     def variant(self) -> str:
@@ -162,6 +167,8 @@ class Lowering:
         bits = [f"{self.op}", f"impl={self.impl}"]
         if self.impl != self.impl_requested:
             bits[-1] += f"(req {self.impl_requested})"
+        if self.interpret:
+            bits.append("interpret")
         bits.append(f"tn={self.tn}:{self.tn_source}")
         bits.append(f"dtype={self.dtype}")
         if self.gather:
@@ -202,6 +209,7 @@ class Lowering:
             "vmem_bytes": self.vmem_bytes,
             "pad_rows": self.pad_rows,
             "pad_cols": self.pad_cols,
+            "interpret": self.interpret,
             "variant": self.variant,
             "version": self.version,
             "plan": {"d": p.d, "d_pad": p.d_pad, "k_pad": p.k_pad,
@@ -216,11 +224,13 @@ class Lowering:
 # predicate from here so kernels and distributed share one budget model).
 # ---------------------------------------------------------------------------
 
-def v1_working_set_bytes(plan: BlockPermPlan, tn: int) -> int:
-    """v1 revisiting kernel per-program working set: the materialized
-    (Br, Bc) fp32 Φ tile plus a double-buffered block pair at width tn
-    (the model ``tune.v1_default_tn`` shrinks against)."""
-    return 4 * plan.Br * plan.Bc + 8 * (plan.Bc + plan.Br) * tn
+def v1_working_set_bytes(plan: BlockPermPlan, tn: int,
+                         op: str = "fwd") -> int:
+    """v1 revisiting kernel working set at tile width ``tn``: one (Br, Bc)
+    fp32 Φ scratch, one double-buffered fp32 block pair and the chunked
+    contraction's temporaries — the κ=1 fused footprint of its op."""
+    return fused_variant_bytes(1, plan.Br, plan.Bc, tn, 4,
+                               "transpose" if op == "transpose" else "fwd", 4)
 
 
 def partial_vmem_bytes(plan: BlockPermPlan, tn: int) -> int:
@@ -320,8 +330,9 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
 
     impl_req = spec.impl
     impl = impl_req
+    on_tpu = jax.default_backend() == "tpu"
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+        impl = "pallas" if on_tpu else "xla"
         t(f"impl: 'auto' -> {impl!r} (backend={jax.default_backend()!r})")
     else:
         t(f"impl: {impl!r} requested")
@@ -354,24 +365,18 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
         if impl == "pallas":
             tclass = "blockrow" if spec.op == "blockrow" else "fwd"
             if tn is None:
-                hit = tune.lookup(eff, n_eff, tclass)
-                if hit is not None:
-                    tn, tn_source = hit.tn, hit.source
-                    t(f"tn: {tn} ({tn_source} winner, class {tclass!r})")
-                else:
-                    tn = tune.heuristic_tn(eff, n_eff, tclass, trace=trace)
-                    tn_source = "heuristic"
-                    t(f"tn: {tn} (heuristic, class {tclass!r})")
+                tn, tn_source = tune.resolve(eff, n_eff, tclass, trace=trace)
             # the partial kernel's own (Br, Bc)-Φ working set may exceed
             # the budget even when the resolved class tile fits: shrink
-            # the tile first, then fall back to the oracle — there is no
-            # v1 partial formulation.
+            # the tile through the narrower legal widths first, then fall
+            # back to the oracle — there is no v1 partial formulation.
             tn_req = tn
-            while tn > MIN_TILE_N and not partial_fits_vmem(eff, tn):
+            narrower = [w for w in tune.legal_tns(n_eff) if w < tn]
+            while narrower and not partial_fits_vmem(eff, tn):
                 t(f"tn={tn} rejected: partial working set "
                   f"{partial_vmem_bytes(eff, tn)} B > VMEM budget "
                   f"{VMEM_BUDGET_BYTES} B")
-                tn //= 2
+                tn = narrower.pop()
             if tn != tn_req:
                 # the record must not claim the request ran as asked — an
                 # explicit/tuned tile that was shrunk is a forced change
@@ -391,13 +396,16 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
             else:
                 vmem = partial_vmem_bytes(eff, tn)
         grid_cols = (None if tn is None else -(-n_eff // tn))
+        if downgrade:
+            health_report.record("lowering.downgrade", detail=downgrade)
         return Lowering(
             plan=eff, op=spec.op, impl=impl, impl_requested=impl_req,
             downgrade=downgrade, tn=tn, tn_source=tn_source,
             dtype=eff.dtype, gather=False, gather_fused=False,
             batch=spec.batch, shard="row", devices=spec.devices,
             n=spec.n, n_loc=n_loc, batch_loc=batch_loc, n_eff=n_eff,
-            grid_cols=grid_cols, vmem_bytes=vmem, pad_rows=0, pad_cols=0)
+            grid_cols=grid_cols, vmem_bytes=vmem, pad_rows=0, pad_cols=0,
+            interpret=impl == "pallas" and not on_tpu)
 
     if impl in _PALLAS_IMPLS:
         variant = spec.op + ("_gather" if spec.gather else "")
@@ -435,28 +443,17 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
             if impl == "pallas_v1":
                 tn = tune.v1_default_tn(eff, n_eff)
                 tn_source = "v1_default"
-                t(f"tn: {tn} (v1 default — block-pair working set)")
+                t(f"tn: {tn} (v1 default — narrowest legal tile)")
             else:
-                hit = tune.lookup(eff, n_loc, variant, batch=batch_loc)
-                if hit is not None:
-                    tn, tn_source = hit.tn, hit.source
-                    t(f"tn: {tn} ({tn_source} winner, class {variant!r}, "
-                      f"batch={batch_loc})")
-                else:
-                    tn = tune.heuristic_tn(eff, n_loc, variant, batch_loc,
-                                           trace=trace)
-                    tn_source = "heuristic"
-                    t(f"tn: {tn} (heuristic, class {variant!r}, "
-                      f"batch={batch_loc})")
+                tn, tn_source = tune.resolve(eff, n_loc, variant, batch_loc,
+                                             trace=trace)
         else:
             t(f"tn: {tn} (explicit)")
 
         if impl == "pallas_v1":
-            vmem = v1_working_set_bytes(eff, tn)
+            vmem = v1_working_set_bytes(eff, tn, spec.op)
         else:
-            vmem = fused_variant_bytes(eff.kappa, eff.Br, eff.Bc, tn,
-                                       eff.stream_itemsize, variant,
-                                       eff.precision.compute_itemsize)
+            vmem = tune._vmem_footprint(eff, tn, variant)
         if not gather_fused:
             if spec.op == "transpose":
                 pad_rows = 0                      # plan.k == plan.k_pad
@@ -482,7 +479,8 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
         gather=spec.gather, gather_fused=gather_fused, batch=spec.batch,
         shard=spec.shard, devices=spec.devices if spec.shard != "none" else 1,
         n=spec.n, n_loc=n_loc, batch_loc=batch_loc, n_eff=n_eff,
-        grid_cols=grid_cols, vmem_bytes=vmem, pad_rows=pad_rows, pad_cols=0)
+        grid_cols=grid_cols, vmem_bytes=vmem, pad_rows=pad_rows, pad_cols=0,
+        interpret=impl in _PALLAS_IMPLS and not on_tpu)
 
 
 _LOWERING_CACHE: Dict[Tuple, Lowering] = {}
@@ -654,7 +652,8 @@ def execute(lw: Lowering, operand: jnp.ndarray,
 
     if lw.gather_fused:
         rmap = row_map_for(plan, row_index)
-        Y = _GATHER_KERNELS[lw.op](plan, operand, rmap, tn=lw.tn)
+        Y = _GATHER_KERNELS[lw.op](plan, operand, rmap, tn=lw.tn,
+                                   interpret=lw.interpret)
         return Y[: plan.k, :n]
 
     if lw.op == "transpose":
@@ -668,8 +667,9 @@ def execute(lw: Lowering, operand: jnp.ndarray,
         # v1 computes in fp32; keep the plan's streaming-precision contract
         # by rounding the input exactly as the bf16 stream would.
         out = _V1_KERNELS[lw.op](plan, _emulate_stream(plan, operand),
-                                 tn=lw.tn)
+                                 tn=lw.tn, interpret=lw.interpret)
     else:
-        out = _V2_KERNELS[lw.op](plan, operand, tn=lw.tn)
+        out = _V2_KERNELS[lw.op](plan, operand, tn=lw.tn,
+                                 interpret=lw.interpret)
     rows = plan.d if lw.op == "transpose" else plan.k
     return out[:rows, :n]

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blockperm import BlockPermPlan
+from repro.core.precision import fp32_matmul
 from repro.health import report as health_report
 from repro.kernels import lowering
 
@@ -454,7 +455,7 @@ def triangular_factor(SA: jnp.ndarray, factorization: str = "qr") -> jnp.ndarray
     if factorization == "qr":
         R = jnp.linalg.qr(SA, mode="r")
     elif factorization == "chol":
-        R = jnp.linalg.cholesky(SA.T @ SA).T  # upper-triangular
+        R = jnp.linalg.cholesky(fp32_matmul(SA.T, SA)).T  # upper-triangular
         if not isinstance(R, jax.core.Tracer) and not bool(
                 jnp.all(jnp.isfinite(R))):
             health_report.record(

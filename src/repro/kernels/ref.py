@@ -18,6 +18,26 @@ from repro.core import hashing, wiring
 from repro.core.blockperm import BlockPermPlan, global_rows_signs
 
 
+# Column-block width of the oracles' contractions.
+_COL_BLOCK = 128
+
+
+def block_einsum(spec: str, phi: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``einsum(spec, phi, x)`` at fp32 HIGHEST, over the column axis ``n``
+    of ``x`` in fixed 128-wide blocks.  The CPU backend's matmul picks its
+    blocking from the shape, so without this a column's result depends on
+    how many columns share the call; with it, every column goes through
+    an identically shaped contraction — the oracle is column-independent,
+    as the kernels are.  ``spec`` names the column axis ``n``."""
+    n = x.shape[-1]
+    nb = -(-n // _COL_BLOCK)
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, nb * _COL_BLOCK - n)])
+    x = x.reshape(*x.shape[:-1], nb, _COL_BLOCK)
+    y = jnp.einsum(spec.replace("n", "jw"), phi, x,
+                   precision=jax.lax.Precision.HIGHEST)
+    return y.reshape(*y.shape[:-2], nb * _COL_BLOCK)[..., :n]
+
+
 def pad_input(plan: BlockPermPlan, A: jnp.ndarray) -> jnp.ndarray:
     """Zero-pad A from (d, n) to (d_pad, n)."""
     d, _ = A.shape
@@ -93,9 +113,7 @@ def flashsketch_ref(plan: BlockPermPlan, A: jnp.ndarray) -> jnp.ndarray:
         h_of_g = pi[ell]                                    # (M,)
         gathered = A_blocks[h_of_g]                         # (M, Bc, n)
         phi = _phi_all_blocks(plan, h_of_g)                 # (M, Br, Bc)
-        Y_blocks = Y_blocks + jnp.einsum(
-            "gbc,gcn->gbn", phi, gathered, precision=jax.lax.Precision.HIGHEST
-        )
+        Y_blocks = Y_blocks + block_einsum("gbc,gcn->gbn", phi, gathered)
     Y = Y_blocks.reshape(plan.k_pad, n) * plan.scale
     return Y[: plan.k]
 
@@ -114,9 +132,7 @@ def flashsketch_transpose_ref(plan: BlockPermPlan, Y: jnp.ndarray) -> jnp.ndarra
     for ell in range(plan.kappa):
         h_of_g = pi[ell]
         phi = _phi_all_blocks(plan, h_of_g)                 # (M, Br, Bc)
-        contrib = jnp.einsum(
-            "gbc,gbn->gcn", phi, Y_blocks, precision=jax.lax.Precision.HIGHEST
-        )                                                   # (M, Bc, n)
+        contrib = block_einsum("gbc,gbn->gcn", phi, Y_blocks)  # (M, Bc, n)
         X_blocks = X_blocks.at[h_of_g].add(contrib)
     X = X_blocks.reshape(plan.d_pad, n) * plan.scale
     return X[: plan.d]
@@ -172,9 +188,7 @@ def blockrow_ref(plan: BlockPermPlan, A: jnp.ndarray) -> jnp.ndarray:
         h_of_g = hh[ell]
         gathered = A_blocks[h_of_g]
         phi = _phi_rows_all_blocks(plan, h_of_g)
-        Y_blocks = Y_blocks + jnp.einsum(
-            "gbc,gcn->gbn", phi, gathered, precision=jax.lax.Precision.HIGHEST
-        )
+        Y_blocks = Y_blocks + block_einsum("gbc,gcn->gbn", phi, gathered)
     scale = plan.scale * math.sqrt(plan.d_pad / plan.k_pad)
     Y = Y_blocks.reshape(plan.k_pad, n) * scale
     return Y[: plan.k]

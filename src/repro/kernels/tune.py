@@ -2,10 +2,11 @@
 
 Two layers:
 
-  * ``resolve_tn(plan, n, variant)`` — the cheap path used by ``ops``
-    dispatch whenever the caller passes ``tn=None``.  Pure python: returns
-    the cached tuned width for this shape class if one exists, else a
-    VMEM-budget heuristic.  Safe to call at trace time (no timing, no jit).
+  * ``resolve(plan, n, variant)`` — the cheap path the lowering engine
+    takes whenever the caller passes ``tn=None``.  Pure python: returns
+    the cached tuned width for this shape class if it is a legal tile
+    (``legal_tns``), else a VMEM-budget heuristic.  Safe to call at trace
+    time (no timing, no jit).
   * ``autotune(plan, n, ...)`` / ``autotune_plan(d, k, n, ...)`` — the
     active path: times real kernel launches over a sweep of ``tn`` (and,
     for ``autotune_plan``, the ``M``/``B_r`` split via ``block_rows``),
@@ -41,15 +42,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.blockperm import (GATHER_VARIANTS, MIN_TILE_N,
-                                  SKETCH_VARIANTS, BlockPermPlan,
-                                  VMEM_BUDGET_BYTES, _next_pow2,
-                                  fused_variant_bytes, make_plan)
+from repro.core.blockperm import (GATHER_VARIANTS, LANE, SKETCH_VARIANTS,
+                                  BlockPermPlan, VMEM_BUDGET_BYTES,
+                                  _next_pow2, fused_variant_bytes, make_plan)
 from repro.kernels import flashsketch as fsk
 
 VARIANTS = SKETCH_VARIANTS + GATHER_VARIANTS
 
-_MIN_TN = MIN_TILE_N
 _MAX_TN = 1024
 
 
@@ -136,40 +135,60 @@ def cache_size() -> int:
 def _vmem_footprint(plan: BlockPermPlan, tn: int, variant: str) -> int:
     return fused_variant_bytes(plan.kappa, plan.Br, plan.Bc, tn,
                                plan.stream_itemsize, variant,
-                               plan.precision.compute_itemsize)
+                               plan.precision.compute_itemsize,
+                               gather_mask=plan.d < plan.d_pad)
+
+
+def legal_tns(n: int, variant: str = "fwd") -> Tuple[int, ...]:
+    """Every tile width the engine may launch at ``n`` effective columns,
+    ascending — the ONE tile rule, on every backend.
+
+    The chip's compiler takes a block whose last dimension is a multiple
+    of the 128-lane tiling or the array's full extent, so a launch of
+    ``n ≤ 128`` columns runs at ``tn = n`` and a wider one at powers of
+    two from 128 up to ``_MAX_TN`` (never past the next power of two of
+    ``n``).  The gather kernels DMA whole lane tiles of a column-padded
+    row stack, so their tiles are always lane multiples."""
+    if n <= LANE and variant not in GATHER_VARIANTS:
+        return (n,)
+    cap = min(_MAX_TN, max(LANE, _n_bucket(n)))
+    return tuple(LANE << i for i in range((cap // LANE).bit_length()))
 
 
 def fused_fits_vmem(plan: BlockPermPlan, n: int, variant: str = "fwd") -> bool:
     """Whether the v2 fused working set (stacked Φ scratch + pipelined
-    blocks) fits the VMEM budget at the smallest tile width.
+    blocks + temporaries) fits the VMEM budget at the narrowest legal tile
+    for ``n`` columns.
 
-    The Φ scratch is (Br, κ·Bc) — independent of ``tn`` — so for very large
-    d_pad/M the fused kernel cannot fit no matter how the tuner shrinks the
-    tile; dispatch falls back to the v1 revisiting kernel in that case.
+    The Φ scratch is (κ, Br, Bc) — independent of ``tn`` — so for very
+    large d_pad/M the fused kernel cannot fit no matter how the tuner
+    shrinks the tile; dispatch falls back to the v1 revisiting kernel in
+    that case.
     """
-    return _vmem_footprint(plan, _MIN_TN, variant) <= VMEM_BUDGET_BYTES
+    return (_vmem_footprint(plan, legal_tns(n, variant)[0], variant)
+            <= VMEM_BUDGET_BYTES)
 
 
 def heuristic_tn(plan: BlockPermPlan, n: int, variant: str = "fwd",
                  batch: int = 1, trace: Optional[list] = None) -> int:
-    """Largest power-of-two tile width that fits the VMEM budget.
+    """Widest legal tile (``legal_tns``) that fits the VMEM budget.
 
-    Prefers ≥128 (TPU lane width) when the problem is wide enough; never
-    exceeds the (power-of-two-rounded) effective column count ``n·batch``
-    (a batched launch folds the batch into the column axis), so small
-    problems are not padded into oblivion.  ``trace`` (a list, appended in
-    place) records every rejected candidate width for ``lowering.explain``.
+    ``n·batch`` is the effective column count (a batched launch folds the
+    batch into the column axis).  When no legal tile fits, the narrowest
+    one is returned and ``fused_fits_vmem`` routes the launch to a
+    recorded downgrade.  ``trace`` (a list, appended in place) records
+    every rejected candidate width for ``lowering.explain``.
     """
-    cap = min(_MAX_TN, _n_bucket(n * max(1, batch)))
-    tn = max(_MIN_TN, cap)
-    while tn > _MIN_TN and _vmem_footprint(plan, tn, variant) > VMEM_BUDGET_BYTES:
+    cands = legal_tns(n * max(1, batch), variant)
+    for tn in reversed(cands):
+        fp = _vmem_footprint(plan, tn, variant)
+        if fp <= VMEM_BUDGET_BYTES:
+            return tn
         if trace is not None:
             trace.append(
                 f"tn={tn} rejected: {variant!r} working set "
-                f"{_vmem_footprint(plan, tn, variant)} B > VMEM budget "
-                f"{VMEM_BUDGET_BYTES} B")
-        tn //= 2
-    return tn
+                f"{fp} B > VMEM budget {VMEM_BUDGET_BYTES} B")
+    return cands[0]
 
 
 def lookup(plan: BlockPermPlan, n: int, variant: str = "fwd",
@@ -183,28 +202,41 @@ def lookup(plan: BlockPermPlan, n: int, variant: str = "fwd",
         return _CACHE.get(cache_key(plan, n, variant, interpret, batch=batch))
 
 
-def resolve_tn(plan: BlockPermPlan, n: int, variant: str = "fwd",
-               batch: int = 1) -> int:
-    """Cache-or-heuristic tile width (the dispatch path, no timing)."""
+def resolve(plan: BlockPermPlan, n: int, variant: str = "fwd",
+            batch: int = 1, trace: Optional[list] = None) -> Tuple[int, str]:
+    """``(tn, source)`` for a launch that did not pin its tile: the tuned or
+    loaded winner of the shape class when it is a legal tile for this
+    exact column count (a class spans a bucket of ``n``), else the VMEM
+    heuristic.  Pure python, safe at trace time; ``trace`` collects the
+    reasoning for ``lowering.explain``."""
     hit = lookup(plan, n, variant, batch=batch)
     if hit is not None:
-        return hit.tn
-    return heuristic_tn(plan, n, variant, batch)
+        if hit.tn in legal_tns(n * max(1, batch), variant):
+            if trace is not None:
+                trace.append(f"tn: {hit.tn} ({hit.source} winner, class "
+                             f"{variant!r}, batch={batch})")
+            return hit.tn, hit.source
+        if trace is not None:
+            trace.append(f"tn: {hit.source} winner {hit.tn} is not a legal "
+                         f"tile at {n * max(1, batch)} columns")
+    tn = heuristic_tn(plan, n, variant, batch, trace=trace)
+    if trace is not None:
+        trace.append(f"tn: {tn} (heuristic, class {variant!r}, "
+                     f"batch={batch})")
+    return tn, "heuristic"
+
+
+def resolve_tn(plan: BlockPermPlan, n: int, variant: str = "fwd",
+               batch: int = 1) -> int:
+    """The tile ``resolve`` picks (the engine's choice, no timing)."""
+    return resolve(plan, n, variant, batch)[0]
 
 
 def v1_default_tn(plan: BlockPermPlan, n: int) -> int:
-    """Tile width for the v1 revisiting kernel (always fp32).
-
-    v1's per-program working set is one double-buffered block pair plus the
-    materialized Φ tile (Br, Bc); for the huge-Bc plans that trigger the
-    v2→v1 fallback the tile width must shrink accordingly.  If the Φ tile
-    alone busts the budget, the minimum tile is returned — that matches the
-    seed kernel's (pre-existing) capability ceiling."""
-    tn = min(128, _n_bucket(n))
-    fixed = 4 * plan.Br * plan.Bc                       # Φ tile, fp32
-    while tn > _MIN_TN and fixed + 8 * (plan.Bc + plan.Br) * tn > VMEM_BUDGET_BYTES:
-        tn //= 2
-    return tn
+    """Tile width for the v1 revisiting kernel (always fp32): the narrowest
+    legal tile, since the plans that take the v2→v1 rung are the ones
+    whose Φ alone nearly fills the budget."""
+    return legal_tns(n)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -232,14 +264,12 @@ _KERNELS = {
 
 def _candidate_tns(plan: BlockPermPlan, n: int, variant: str,
                    batch: int = 1) -> Tuple[int, ...]:
-    cap = min(_MAX_TN, _n_bucket(n * max(1, batch)))
-    tns = []
-    tn = _MIN_TN
-    while tn <= cap:
-        if _vmem_footprint(plan, tn, variant) <= VMEM_BUDGET_BYTES:
-            tns.append(tn)
-        tn *= 2
-    return tuple(tns) or (_MIN_TN,)
+    """The legal tiles whose working set fits the budget (the narrowest
+    legal tile when none does)."""
+    cands = legal_tns(n * max(1, batch), variant)
+    fits = tuple(tn for tn in cands
+                 if _vmem_footprint(plan, tn, variant) <= VMEM_BUDGET_BYTES)
+    return fits or cands[:1]
 
 
 def _time_call(fn, *args, warmup: int = 1, iters: int = 3) -> float:
@@ -287,28 +317,20 @@ def autotune(
         return hit
     kernel = _KERNELS[variant]
     n_eff = n * max(1, batch)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     best: Optional[TuneResult] = None
-    last_error: Optional[Exception] = None
     for tn in (tns or _candidate_tns(plan, n, variant, batch)):
         n_pad = ((n_eff + tn - 1) // tn) * tn
         operand = _make_operand(plan, n_pad, variant)
         fn = jax.jit(lambda x, _tn=tn: kernel(plan, x, tn=_tn, interpret=interpret))
-        try:
-            us = _time_call(fn, operand, warmup=warmup, iters=iters)
-        except Exception as e:  # a failed candidate only narrows the sweep
-            last_error = e
-            continue
+        # every candidate is a legal tile that fits the budget, so a
+        # failure is a kernel or model bug: it propagates instead of
+        # silently narrowing the sweep
+        us = _time_call(fn, operand, warmup=warmup, iters=iters)
         cand = TuneResult(tn=tn, time_us=us, source="tuned")
         if _is_better(cand, best):
             best = cand
-    if best is None:
-        # every candidate failed — that is a bug signal, not a tuning result
-        warnings.warn(
-            f"autotune: all tn candidates failed for {plan.describe()} "
-            f"variant={variant!r}; falling back to heuristic "
-            f"(last error: {last_error!r})")
-        best = TuneResult(tn=heuristic_tn(plan, n, variant, batch),
-                          source="heuristic")
     with _CACHE_LOCK:
         _CACHE[key] = best
         _bump_generation()

@@ -15,12 +15,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) over ('pod','data','model') — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (tests use tiny ones, e.g. (2,2) on 4 host devices)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests use tiny ones, e.g. (2,2) on 4 host devices).
+
+    Axes are ``Auto``: the compiler propagates shardings, as the code here
+    assumes (JAX 0.9's ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    whose sharding-in-types rejects e.g. an embedding gather across
+    differently sharded operands)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes_of(mesh) -> Tuple[str, ...]:

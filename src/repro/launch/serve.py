@@ -26,6 +26,7 @@ import numpy as np
 from repro.health import report as health_report
 from repro.health.inject import adversarial_input, inject_nan
 from repro.serving import SketchRequest, ThreadedServer
+from repro.compile_cache import enable_compile_cache
 
 
 def _print_stats(label, responses, srv):
@@ -120,6 +121,7 @@ def main(argv=None):
                          "and check the no-silent-failures contract")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke()

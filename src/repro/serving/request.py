@@ -109,6 +109,9 @@ class SketchResponse:
     batch_size: int = 0            # coalesced group size that served it
     attempts: int = 0              # sketch draws consumed for this request
     detail: str = ""
+    # ``kernels.lowering.Lowering`` of the coalesced launch whose output
+    # this response carries (sketch requests; a solve's is on its result)
+    lowering: Any = None
 
     @property
     def served(self) -> bool:

@@ -213,20 +213,20 @@ class SketchServer:
         n = group.shape[1]
         # resolve the tile ONCE against the tuner's batched shape class;
         # every launch of the group reuses it (one lowering, one jit key)
-        tn = lowering.lower(plan, lowering.LaunchSpec(
+        lw = lowering.lower(plan, lowering.LaunchSpec(
             op="fwd", n=n, impl=self.impl, tn=None, dtype=dtype,
-            batch=len(live))).tn
+            batch=len(live)))
         stacked = jnp.stack([jnp.asarray(r.operand) for r in live])
         Y = self._timed(ops.sketch_apply_batched, plan, stacked,
-                        self.impl, tn, dtype)
+                        self.impl, lw.tn, dtype)
         Y = np.asarray(Y)
         for j, req in enumerate(live):
             self._finish_sketch(req, group, decision, np.asarray(Y[j]),
-                                batch=len(live))
+                                batch=len(live), lw=lw)
 
     def _finish_sketch(self, req: SketchRequest, group: Group,
                        decision: DegradeDecision, Yj: np.ndarray,
-                       batch: int) -> None:
+                       batch: int, lw=None) -> None:
         report = HealthReport(op="serve.sketch", attempts=1)
         report.findings.extend(decision.findings)
         if not self.guard:
@@ -234,7 +234,7 @@ class SketchServer:
                 request_id=req.request_id, tenant=req.tenant, kind=req.kind,
                 status=DEGRADED if decision.level >= 2 else OK, result=Yj,
                 health=report, latency_s=self.clock.now() - req.arrival_s,
-                batch_size=batch, attempts=1))
+                batch_size=batch, attempts=1, lowering=lw))
             return
         A = np.asarray(req.operand)
         verdict = self._guard_slice(A, Yj, report)
@@ -277,7 +277,7 @@ class SketchServer:
             request_id=req.request_id, tenant=req.tenant, kind=req.kind,
             status=status, result=result, health=report,
             latency_s=self.clock.now() - req.arrival_s, batch_size=batch,
-            attempts=report.attempts))
+            attempts=report.attempts, lowering=lw if result is Yj else None))
 
     def _guard_slice(self, A: np.ndarray, Yj: np.ndarray,
                      report: HealthReport) -> str:

@@ -63,6 +63,7 @@ def solve_preset(A, b, preset, *, seed: int = 0, impl: str = "auto"):
 
     from repro.configs.flashsketch_paper import SOLVER_PRESETS, solver_sketch_rows
     from repro.core.blockperm import make_plan
+    from repro.core.precision import fp32_matmul
     from repro.solvers.sketch_precondition import SolveResult
 
     if isinstance(preset, str):
@@ -73,7 +74,8 @@ def solve_preset(A, b, preset, *, seed: int = 0, impl: str = "auto"):
         plan = make_plan(d, k, kappa=preset.kappa, s=preset.s, seed=seed,
                          dtype=preset.dtype)
         x = sketch_and_solve_lstsq(plan, A, b, impl=impl)
-        relres = float(jnp.linalg.norm(A @ x - b) / jnp.linalg.norm(b))
+        relres = float(jnp.linalg.norm(fp32_matmul(A, x) - b)
+                       / jnp.linalg.norm(b))
         return SolveResult(x=x, iterations=0, relres=relres, converged=True)
     if preset.num_sketches > 1:
         return multisketch_lstsq(

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.blockperm import (BlockPermPlan, FAMILY_DEFAULT_S,
                                   make_plan)
+from repro.core.precision import fp32_matmul
 from repro.kernels import ops
 from repro.solvers import sketch_precondition as sp
 
@@ -206,7 +207,8 @@ def multisketch_lstsq(
     while total_iters < budget:
         res = sp.lsqr(A, b, R=R, x0=x, tol=tol, max_iters=iters_per_round)
         total_iters += res.iterations
-        new_relres = float(jnp.linalg.norm(A @ res.x - b)) / max(bnorm, 1e-30)
+        new_relres = (float(jnp.linalg.norm(fp32_matmul(A, res.x) - b))
+                      / max(bnorm, 1e-30))
         prev_relres = relres
         if new_relres < relres:
             x, relres = res.x, new_relres

@@ -22,7 +22,8 @@ the preconditioner never biases the fixed point.
 
 Precision notes: the sketch + factorization run in the plan's streaming
 precision (fp32 or bf16-streamed); the LSQR/CG iteration runs in the dtype
-of ``A``/``b`` (pass float64 arrays under ``jax.config jax_enable_x64`` for
+of ``A``/``b``, its products at full precision (``fp32_matmul`` — the TPU
+default would round fp32 inputs to bf16) (pass float64 arrays under ``jax.config jax_enable_x64`` for
 residuals below fp32 rounding).  A bf16 sketch only perturbs R — i.e. costs
 a few extra iterations — never the attainable accuracy.
 """
@@ -39,6 +40,7 @@ import jax.scipy.linalg as jsl
 from repro.configs import flashsketch_paper
 from repro.core.blockperm import (BlockPermPlan, FAMILY_DEFAULT_S,
                                   make_plan)
+from repro.core.precision import fp32_matmul
 from repro.kernels import lowering, ops
 
 
@@ -87,8 +89,9 @@ def _right_precond_ops(
     row-sharded A — see ``repro.distributed.dist_solvers``); the
     right-preconditioner composition is identical either way.
     """
-    mv = matvec if matvec is not None else (lambda v: A @ v)
-    rmv = rmatvec if rmatvec is not None else (lambda u: A.T @ u)
+    mv = matvec if matvec is not None else (lambda v: fp32_matmul(A, v))
+    rmv = rmatvec if rmatvec is not None else (
+        lambda u: fp32_matmul(A.T, u))
     if R is None:
         return mv, rmv, _identity
     Rt = R.T
@@ -165,7 +168,8 @@ def _lsqr_recurrence(matvec, rmatvec, unprec, base_matvec, b, x0, nvars,
 def _lsqr_jit(A, b, R, x0, *, tol: float, max_iters: int, has_R: bool):
     """Dense-array LSQR chunk (shape-keyed jit cache over A/b/R)."""
     matvec, rmatvec, unprec = _right_precond_ops(A, R if has_R else None)
-    return _lsqr_recurrence(matvec, rmatvec, unprec, lambda v: A @ v,
+    return _lsqr_recurrence(matvec, rmatvec, unprec,
+                            lambda v: fp32_matmul(A, v),
                             b, x0, A.shape[1], tol=tol, max_iters=max_iters)
 
 
@@ -212,7 +216,7 @@ def lsqr(
         return _lsqr_jit(A, b, R_arg, x, tol=float(tol),
                          max_iters=chunk, has_R=R is not None)
 
-    return _restarted_drive(run_chunk, lambda x: A @ x - b, b, x0,
+    return _restarted_drive(run_chunk, lambda x: fp32_matmul(A, x) - b, b, x0,
                             nvars=A.shape[1], tol=tol,
                             max_iters=int(max_iters),
                             restart_every=restart_every)
@@ -354,7 +358,8 @@ def pcg_normal(
     """
     x, it, normal_relres = _pcg_normal_jit(A, b, R, tol=float(tol),
                                            max_iters=int(max_iters))
-    relres = float(jnp.linalg.norm(A @ x - b) / jnp.linalg.norm(b))
+    relres = float(jnp.linalg.norm(fp32_matmul(A, x) - b)
+                   / jnp.linalg.norm(b))
     return SolveResult(x=x, iterations=int(it), relres=relres,
                        converged=bool(float(normal_relres) <= tol))
 

@@ -125,7 +125,7 @@ def test_partial_pallas_vmem_overflow_falls_back(rng):
     launch the partial kernel — impl='pallas' silently degrades to the jnp
     oracle (there is no v1 partial), mirroring ops' fused→v1 fallback."""
     from repro.distributed import partial_fits_vmem
-    plan = plan_for_mesh(262_144, 1024, 8, kappa=2)
+    plan = make_plan(262_144, 1024, kappa=2, block_rows=128)   # M=8
     assert not partial_fits_vmem(plan, 8)
     A = jnp.asarray(rng.normal(size=(262_144, 4)), jnp.float32)
     Ap = kref.pad_input(plan, A)

@@ -191,6 +191,22 @@ def test_tune_cache_roundtrip(tmp_path):
     tune.clear_cache()
 
 
+def test_autotune_raises_on_a_failed_candidate(monkeypatch):
+    """A candidate that fails to run is a kernel or VMEM-model bug: the
+    sweep raises instead of quietly dropping the tile."""
+    tune.clear_cache()
+    plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=2)
+
+    def broken(plan, X, *, tn, interpret=False):
+        raise RuntimeError(f"compile refused tn={tn}")
+
+    monkeypatch.setitem(tune._KERNELS, "fwd", broken)
+    with pytest.raises(RuntimeError, match="compile refused"):
+        tune.autotune(plan, 256, "fwd", iters=1, warmup=0)
+    assert tune.lookup(plan, 256, "fwd") is None
+    tune.clear_cache()
+
+
 def test_tune_key_separates_dtype_and_variant():
     plan = make_plan(512, 128, kappa=4, s=2, block_rows=32, seed=1)
     k_f32 = tune.cache_key(plan, 100, "fwd")

@@ -169,6 +169,26 @@ def test_lowering_snapshot_matches_golden():
             f"If intended, regenerate with REGEN_LOWERING_SNAPSHOT=1.")
 
 
+def test_engine_tiles_obey_the_chip_tile_rule():
+    """Every tile the engine picks itself — heuristic, tuned, v1 default,
+    the row-shard shrink — is a multiple of the 128-lane tiling or the
+    launch's full column count (the chip's block rule), on every backend;
+    gather tiles are always lane multiples (whole-tile row DMAs)."""
+    plans = _plans()
+    checked = 0
+    for plan_name, spec_kwargs in _grid():
+        lw = lowering.lower(plans[plan_name],
+                            lowering.LaunchSpec(**spec_kwargs))
+        if lw.tn is None or lw.tn_source.startswith("explicit"):
+            continue
+        assert lw.tn in tune.legal_tns(lw.n_eff, lw.variant), lw.describe()
+        assert lw.tn % 128 == 0 or lw.tn == lw.n_eff, lw.describe()
+        if lw.gather_fused:
+            assert lw.tn % 128 == 0, lw.describe()
+        checked += 1
+    assert checked > 100
+
+
 # ---------------------------------------------------------------------------
 # 2. cost_of(lowering) == legacy cost entry points, every grid point
 # ---------------------------------------------------------------------------
@@ -250,7 +270,7 @@ def test_downgrade_gather_materialized_recorded():
 
 
 def test_row_shard_vmem_fallback_to_oracle():
-    plan = plan_for_mesh(262_144, 1024, 8, kappa=2)
+    plan = make_plan(262_144, 1024, kappa=2, block_rows=128)   # M=8
     assert not lowering.partial_fits_vmem(plan, 8)
     lw = lowering.lower(plan, lowering.LaunchSpec(
         op="fwd", n=8, impl="pallas", shard="row", devices=8))
@@ -260,7 +280,7 @@ def test_row_shard_vmem_fallback_to_oracle():
 def test_row_shard_tile_shrink_is_recorded():
     """An explicit tile shrunk by the partial VMEM budget must not be
     reported as 'the request ran as asked' (review finding)."""
-    plan = plan_for_mesh(65_536, 1024, 8, kappa=4)
+    plan = make_plan(65_536, 1024, kappa=4, block_rows=128)    # M=8
     lw = lowering.lower(plan, lowering.LaunchSpec(
         op="fwd", n=64, impl="pallas", tn=512, shard="row", devices=8))
     if lw.tn == 512:
@@ -283,6 +303,20 @@ def test_lowering_cache_does_not_grow_across_generations():
     lowering.lower(plan, spec)
     assert lowering.lowering_cache_size() == 1
     tune.clear_cache()
+
+
+def test_interpret_mode_is_on_the_record():
+    """Off the TPU a Pallas lowering interprets and says so; the oracle
+    never claims to; describe() shows it."""
+    plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=4)
+    for impl in ("pallas", "pallas_v1"):
+        lw = lowering.lower(plan, lowering.LaunchSpec(op="fwd", n=64,
+                                                      impl=impl))
+        assert lw.interpret and "interpret" in lw.describe()
+        assert lw.to_json()["interpret"] is True
+    lw = lowering.lower(plan, lowering.LaunchSpec(op="fwd", n=64))
+    assert lw.impl == "xla" and not lw.interpret
+    assert "interpret" not in lw.describe()
 
 
 def test_no_downgrade_records_none():
@@ -316,10 +350,10 @@ def test_lowering_cache_hits_and_tuner_invalidation():
     assert a is b                              # memoized record
     # a tuned winner landing bumps the generation and re-resolves
     key = tune.cache_key(plan, 200, "fwd")
-    tune._CACHE[key] = tune.TuneResult(tn=16, time_us=1.0, source="tuned")
+    tune._CACHE[key] = tune.TuneResult(tn=128, time_us=1.0, source="tuned")
     tune._bump_generation()
     c = lowering.lower(plan, spec)
-    assert c is not b and c.tn == 16 and c.tn_source == "tuned"
+    assert c is not b and c.tn == 128 and c.tn_source == "tuned"
     tune.clear_cache()
 
 
@@ -434,7 +468,7 @@ def test_autotune_plan_winner_served_to_batched_resolve(monkeypatch):
     tune.clear_cache()
 
     def fake_autotune(plan, n, variant="fwd", batch=1, **kw):
-        res = tune.TuneResult(tn=32, time_us=1.0, source="tuned")
+        res = tune.TuneResult(tn=64, time_us=1.0, source="tuned")
         tune._CACHE[tune.cache_key(plan, n, variant, batch=batch)] = res
         tune._bump_generation()
         return res
@@ -444,9 +478,9 @@ def test_autotune_plan_winner_served_to_batched_resolve(monkeypatch):
     plan, res = tune.autotune_plan(512, 128, 4, kappa=2, s=2, batch=B)
     # the winner must be visible through the SAME key builder the readers
     # use — batched consult included
-    assert tune.resolve_tn(plan, 4, "fwd", batch=B) == res.tn == 32
+    assert tune.resolve_tn(plan, 4, "fwd", batch=B) == res.tn == 64
     hit = tune.lookup(plan, 4, "fwd", batch=B)
-    assert hit is not None and hit.tn == 32
+    assert hit is not None and hit.tn == 64
     tune.clear_cache()
 
 
@@ -454,14 +488,14 @@ def test_batched_cache_key_roundtrips_through_json(tmp_path):
     tune.clear_cache()
     plan = make_plan(512, 128, kappa=2, s=2, block_rows=32, seed=1)
     key = tune.cache_key(plan, 4, "fwd_gather", batch=16)
-    tune._CACHE[key] = tune.TuneResult(tn=64, time_us=2.5, source="tuned")
+    tune._CACHE[key] = tune.TuneResult(tn=128, time_us=2.5, source="tuned")
     path = str(tmp_path / "cache.json")
     assert tune.save_cache(path) == 1
     tune.clear_cache()
     gen_before = tune.cache_generation()
     assert tune.load_cache(path) == 1
     assert tune.cache_generation() > gen_before   # loaders invalidate
-    assert tune.resolve_tn(plan, 4, "fwd_gather", batch=16) == 64
+    assert tune.resolve_tn(plan, 4, "fwd_gather", batch=16) == 128
     hit = tune.lookup(plan, 4, "fwd_gather", batch=16)
     assert hit.source == "loaded"
     tune.clear_cache()
@@ -474,15 +508,15 @@ def test_lowering_sees_freshly_loaded_winner(tmp_path):
     tune.clear_cache()
     lowering.clear_lowering_cache()
     plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=4)
-    spec = lowering.LaunchSpec(op="fwd", n=96, impl="pallas")
+    spec = lowering.LaunchSpec(op="fwd", n=300, impl="pallas")
     before = lowering.lower(plan, spec)
-    assert before.tn_source == "heuristic"
-    tune._CACHE[tune.cache_key(plan, 96, "fwd")] = tune.TuneResult(
-        tn=16, time_us=1.0, source="tuned")
+    assert before.tn_source == "heuristic" and before.tn != 128
+    tune._CACHE[tune.cache_key(plan, 300, "fwd")] = tune.TuneResult(
+        tn=128, time_us=1.0, source="tuned")
     path = str(tmp_path / "cache.json")
     tune.save_cache(path)
     tune.clear_cache()
     tune.load_cache(path)
     after = lowering.lower(plan, spec)
-    assert after.tn == 16 and after.tn_source == "loaded"
+    assert after.tn == 128 and after.tn_source == "loaded"
     tune.clear_cache()

@@ -272,9 +272,9 @@ def test_sketch_vectors_uses_batched_cache_winner(monkeypatch, rng):
     to BOTH batch entry points."""
     tune.clear_cache()
     plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=4)
-    B = 6
+    B = 300
     key = tune.cache_key(plan, 1, "fwd", batch=B)
-    tune._CACHE[key] = tune.TuneResult(tn=16, time_us=1.0, source="tuned")
+    tune._CACHE[key] = tune.TuneResult(tn=128, time_us=1.0, source="tuned")
     try:
         seen = []
         orig = lowering.execute
@@ -287,6 +287,6 @@ def test_sketch_vectors_uses_batched_cache_winner(monkeypatch, rng):
         x = jnp.asarray(rng.normal(size=(B, 256)), jnp.float32)
         ops.sketch_vectors(plan, x, "pallas")
         ops.sketch_apply_batched(plan, x[:, :, None], "pallas")
-        assert seen == [16, 16]
+        assert seen == [128, 128]
     finally:
         tune.clear_cache()

@@ -86,6 +86,19 @@ def test_coalescing_one_launch_per_plan_shape(rng):
                                   plan, req.operand)))
 
 
+def test_sketch_response_carries_the_served_lowering(rng):
+    """Each coalesced response names the launch that produced it: the
+    group's batched lowering, one record for the whole group."""
+    srv = _server(max_batch=4)
+    tickets = [srv.submit(_req(rng)) for _ in range(3)]
+    srv.clock.advance(0.02)
+    srv.run_pending()
+    lws = [srv.poll(t).lowering for t in tickets]
+    assert all(lw is lws[0] for lw in lws)
+    assert lws[0].batch == 3 and lws[0].op == "fwd"
+    assert lws[0].plan == srv.plans.resolve("t", PARAMS)
+
+
 def test_solve_request_served_healthy(rng):
     srv = _server()
     A = _operand(rng, n=8)

@@ -62,7 +62,6 @@ _SCRIPT = textwrap.dedent("""
         max_diff = max(jax.tree.leaves(diffs))
 
     # ---- shard_map pod-axis gradient compression
-    from jax.experimental.shard_map import shard_map
     pod_mesh = mesh_lib.make_mesh((2,), ("pod",))
     ccfg = gc.CompressConfig(ratio=4, min_bucket=256)
     g_global = {"w": jnp.asarray(np.random.default_rng(0)
@@ -75,7 +74,7 @@ _SCRIPT = textwrap.dedent("""
         return gh["w"][None], ne["w"][None]
 
     with pod_mesh:
-        gh, ne = shard_map(
+        gh, ne = jax.shard_map(
             per_pod, mesh=pod_mesh,
             in_specs=(P("pod", None), P("pod", None)),
             out_specs=(P("pod", None), P("pod", None)))(
