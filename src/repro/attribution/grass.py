@@ -22,6 +22,7 @@ from repro.attribution import mlp as mlp_lib
 from repro.core import hashing
 from repro.core.variants import SketchBase, make_sketch
 from repro.health import report as health_report
+from repro.tracing import scope, scoped
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +88,12 @@ class GrassPipeline:
     counter).  The mask is computed inside the jitted scan (a ``jnp.where``
     per chunk), so the guarded path costs one finiteness reduction per
     gradient row.
+
+    Tracing: each chunk's three steps carry the program scopes
+    ``repro.grass.grads``, ``repro.grass.quarantine`` and
+    ``repro.grass.sketch`` (``repro.tracing``), so a device trace splits
+    the scan's time by step; ``featurize`` runs under the host span
+    ``repro.grass.featurize``.
     """
 
     def __init__(self, cfg: GrassPipelineConfig, params, mesh=None,
@@ -137,10 +144,14 @@ class GrassPipeline:
                 the sketch and flagged — jit-compatible (a where, not a
                 branch)."""
                 xb, yb = xy
-                grads = jax.vmap(lambda x, y: self._gfn(p_, x, y))(xb, yb)
-                ok = jnp.all(jnp.isfinite(grads), axis=1)
-                grads = jnp.where(ok[:, None], grads, 0.0)
-                return sketch_chunk(grads), ~ok     # (c, k), (c,) per chunk
+                with scope("repro.grass.grads"):
+                    grads = jax.vmap(lambda x, y: self._gfn(p_, x, y))(xb, yb)
+                with scope("repro.grass.quarantine"):
+                    ok = jnp.all(jnp.isfinite(grads), axis=1)
+                    grads = jnp.where(ok[:, None], grads, 0.0)
+                with scope("repro.grass.sketch"):
+                    feats = sketch_chunk(grads)
+                return feats, ~ok                   # (c, k), (c,) per chunk
 
             if mesh is None:
                 _, (feats, bad) = jax.lax.scan(
@@ -168,6 +179,7 @@ class GrassPipeline:
 
         self._featurize = jax.jit(featurize)
 
+    @scoped("repro.grass.featurize")
     def featurize(self, xs, ys) -> jnp.ndarray:
         """Sketched features for a batch; quarantines non-finite rows.
 

@@ -1,4 +1,5 @@
-"""Structured health reporting for guarded sketch execution.
+"""Structured health reporting for guarded sketch execution, and the
+program's counter registry.
 
 BlockPerm-SJLT is an oblivious subspace embedding *with failure
 probability δ* — the κ / sampling-factor analysis explicitly trades GPU
@@ -14,10 +15,14 @@ module is the vocabulary for the "detect" half:
     taken (re-draws, κ bumps, sampling bumps) and quarantine counts.
     Attached to ``solvers.SolveResult.health`` and printable via
     ``describe()`` / serializable via ``to_json()``.
-  * a process-global **event counter registry** — every guard records
-    pass/fail events here (``record``), so long-running jobs can export
-    one counters JSON (``counters_json``) and ``engine.explain`` can show
-    the guard activity of the process alongside the lowering trace.
+  * the program's process-global **event counter registry** — the one
+    place every layer counts events (``record``): guard verdicts and
+    recovery rungs, lowering downgrades, and the lowering memo's
+    ``lowering.memo_hit`` / ``lowering.memo_miss`` per ``lowering.lower``
+    call.  Long-running jobs export one counters JSON (``counters_json``);
+    ``engine.explain`` prints them on its ``counters:`` line alongside
+    the lowering trace.  Program spans and scopes (time, not counts) are
+    ``repro.tracing``'s.
 
 This module is dependency-free (no jax, no repro.kernels) so low layers
 (``kernels.lowering``, ``kernels.ops``, ``kernels.tune``) can import it
@@ -165,7 +170,7 @@ class HealthReport:
 
 
 # ---------------------------------------------------------------------------
-# Process-global guard-event counters.
+# Process-global event counters (guards, recoveries, the lowering memo).
 # ---------------------------------------------------------------------------
 
 _LOCK = threading.Lock()
@@ -175,12 +180,13 @@ _RECENT: List[Tuple[str, str]] = []   # (event, detail) ring for diagnostics
 
 
 def record(event: str, n: int = 1, detail: Optional[str] = None) -> None:
-    """Count one guard/recovery event process-wide.
+    """Count one event process-wide.
 
     Event names are dotted paths: ``guard.<name>.<status>`` for guard
     verdicts, ``policy.<action>`` for escalation-ladder rungs,
     ``tune.cache_corrupt`` / ``factor.chol_downgrade`` / ``grass.quarantined``
-    for layer-specific recoveries.
+    for layer-specific recoveries, ``lowering.downgrade`` and
+    ``lowering.memo_hit`` / ``lowering.memo_miss`` for the lowering engine.
     """
     with _LOCK:
         _COUNTERS[event] = _COUNTERS.get(event, 0) + n
@@ -190,7 +196,7 @@ def record(event: str, n: int = 1, detail: Optional[str] = None) -> None:
 
 
 def counters() -> Dict[str, int]:
-    """Snapshot of the process-wide guard-event counters."""
+    """Snapshot of the process-wide event counters."""
     with _LOCK:
         return dict(_COUNTERS)
 
@@ -217,7 +223,7 @@ def summarize_counters(max_items: int = 8) -> str:
     """One-line counter summary for ``engine.explain`` output."""
     snap = counters()
     if not snap:
-        return "no guard events recorded"
+        return "no events recorded"
     items = sorted(snap.items())
     shown = ", ".join(f"{k}={v}" for k, v in items[:max_items])
     if len(items) > max_items:

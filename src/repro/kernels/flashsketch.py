@@ -47,6 +47,13 @@ Both paths build Φ from counter-based hashes bit-identical to ``ref.py`` /
 ``core.blockperm.dense_block``; the block wiring arrives as a scalar-prefetch
 table so BlockSpec index_maps do data-dependent block gathering (the
 Pallas-idiomatic realization of the paper's App. D on-the-fly wiring).
+
+Program spans and scopes (``repro.tracing``): ``repro.kernel.tables`` (the
+wiring tables), ``repro.kernel.stream`` / ``repro.kernel.row_stack`` (the
+operand's cast and relayout) and, around each ``pallas_call`` launch,
+``repro.kernel.<kernel>`` — the same stable name the ``pallas_call`` gets
+(``flashsketch_fwd``, ``flashsketch_transpose``, ``flashsketch_fwd_gather``,
+``flashsketch_partial``, ``flashsketch_blockrow*``, ``*_v1``).
 """
 from __future__ import annotations
 
@@ -65,6 +72,7 @@ from repro.core import precision as precision_mod
 from repro.core.blockperm import (GLOBAL_FAMILY_TAG, VMEM_BUDGET_BYTES,
                                   BlockPermPlan, phi_chunk)
 from repro.kernels import ref as kref
+from repro.tracing import scope, scoped
 
 
 # ---------------------------------------------------------------------------
@@ -113,6 +121,7 @@ def _inv_neighbor_table(plan: BlockPermPlan) -> np.ndarray:
     ).astype(np.int32)
 
 
+@scoped("repro.kernel.tables")
 @functools.lru_cache(maxsize=None)
 def _blockrow_table(plan: BlockPermPlan) -> np.ndarray:
     """(κ, M) iid wiring, forced to concrete numpy so the wrappers stay
@@ -131,6 +140,7 @@ def _global_table(M: int) -> np.ndarray:
     return np.tile(np.arange(M, dtype=np.int32)[:, None], (1, M))
 
 
+@scoped("repro.kernel.tables")
 def _fwd_phi_and_table(plan: BlockPermPlan):
     """(phi_fn, prefetch table) for the forward/gather launch."""
     if plan.is_global:
@@ -138,6 +148,7 @@ def _fwd_phi_and_table(plan: BlockPermPlan):
     return _phi_tile, _fwd_neighbor_table(plan)
 
 
+@scoped("repro.kernel.tables")
 def _transpose_phi_and_table(plan: BlockPermPlan):
     """(phi_fn, prefetch table) for the transpose launch."""
     if plan.is_global:
@@ -536,7 +547,16 @@ def _io_specs(transpose: bool):
     return (_k_spec, _d_spec) if transpose else (_d_spec, _k_spec)
 
 
-def _run_v1(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
+def _launch(name: str, kernel, args, **call_kwargs):
+    """One ``pallas_call`` launch named ``name``, under the program span
+    and scope ``repro.kernel.<name>``: in an eager call the span covers the
+    kernel body's trace, its lowering, the program load and the enqueue."""
+    with scope(f"repro.kernel.{name}"):
+        return pl.pallas_call(kernel, name=name, **call_kwargs)(*args)
+
+
+def _run_v1(plan, kernel, tab, operand, n, tn, interpret, transpose=False, *,
+            name: str):
     """v1 launcher.  ``n`` may be ragged (``n % tn != 0``): the grid covers
     ⌈n/tn⌉ column tiles and the edge tile is handled by the Pallas
     machinery itself (masked loads/stores on TPU, internal pad+slice in
@@ -559,17 +579,18 @@ def _run_v1(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
         out_specs=out_spec(plan, tn, lambda j, g, l, tab_ref: (g, j)),
         scratch_shapes=[pltpu.VMEM((plan.Br, plan.Bc), jnp.float32)],
     )
-    out = pl.pallas_call(
-        kernel,
+    out = _launch(
+        name, kernel, (jnp.asarray(tab), operand),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
-    )(jnp.asarray(tab), operand)
+    )
     return out if transpose else out.reshape(plan.k_pad, n)
 
 
-def _run_fused(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
+def _run_fused(plan, kernel, tab, operand, n, tn, interpret, transpose=False,
+               *, name: str):
     """v2 launcher: grid (M, n/tn), κ pipelined views of one operand, Φ scratch.
 
     The same operand is passed κ times — each view has its own BlockSpec whose
@@ -609,19 +630,20 @@ def _run_fused(plan, kernel, tab, operand, n, tn, interpret, transpose=False):
         out_specs=out_spec(plan, tn, lambda g, j, tab_ref: (g, j)),
         scratch_shapes=[pltpu.VMEM((plan.kappa, plan.Br, plan.Bc), cdt)],
     )
-    out = pl.pallas_call(
-        kernel,
+    out = _launch(
+        name, kernel, (jnp.asarray(tab), *([operand] * plan.kappa)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
         # j must run sequentially per g (Φ scratch is built at j == 0);
         # g tiles are independent and may be megacore-partitioned.
         compiler_params=_compiler_params(("parallel", "arbitrary")),
-    )(jnp.asarray(tab), *([operand] * plan.kappa))
+    )
     return out if transpose else out.reshape(plan.k_pad, n)
 
 
-def _run_fused_gather(plan, kernel, tab, row_map, rows, tn, interpret):
+def _run_fused_gather(plan, kernel, tab, row_map, rows, tn, interpret, *,
+                      name: str):
     """Gather launcher: grid (M, n_pad/tn) over the ``_row_stack`` operand
     ``rows`` (``(d_src, 1, n_pad)``, left in HBM — ANY memory space);
     masked rows arrive via in-kernel DMA driven by the scalar-prefetched
@@ -646,16 +668,18 @@ def _run_fused_gather(plan, kernel, tab, row_map, rows, tn, interpret):
             pltpu.SemaphoreType.DMA(()),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
+    out = _launch(
+        name, kernel,
+        (jnp.asarray(tab), jnp.asarray(row_map, jnp.int32), rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((plan.M, plan.Br, n_pad), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(("parallel", "arbitrary")),
-    )(jnp.asarray(tab), jnp.asarray(row_map, jnp.int32), rows)
+    )
     return out.reshape(plan.k_pad, n_pad)
 
 
+@scoped("repro.kernel.stream")
 def _stream(plan: BlockPermPlan, operand: jnp.ndarray) -> jnp.ndarray:
     """Quantize the operand into the plan's streaming dtype.
 
@@ -669,6 +693,7 @@ def _stream(plan: BlockPermPlan, operand: jnp.ndarray) -> jnp.ndarray:
         operand, plan.precision, seed=plan.seed)
 
 
+@scoped("repro.kernel.row_stack")
 def _row_stack(plan: BlockPermPlan, operand: jnp.ndarray,
                tn: int) -> jnp.ndarray:
     """The gather kernels' source: the stream-quantized operand as a
@@ -703,7 +728,7 @@ def flashsketch_pallas(
         _fused_fwd_kernel, plan=plan, scale=plan.scale, phi_fn=phi_fn
     )
     return _run_fused(plan, kernel, tab, _stream(plan, A), n=n, tn=tn,
-                      interpret=interpret)
+                      interpret=interpret, name="flashsketch_fwd")
 
 
 def flashsketch_transpose_pallas(
@@ -720,7 +745,8 @@ def flashsketch_transpose_pallas(
     kernel = functools.partial(_fused_transpose_kernel, plan=plan,
                                scale=plan.scale, phi_fn=phi_fn)
     return _run_fused(plan, kernel, tab, _stream(plan, Y), n=n, tn=tn,
-                      interpret=interpret, transpose=True)
+                      interpret=interpret, transpose=True,
+                      name="flashsketch_transpose")
 
 
 def flashsketch_pallas_gather(
@@ -756,7 +782,8 @@ def flashsketch_pallas_gather(
         tn=tn)
     return _run_fused_gather(plan, kernel, tab, row_map,
                              _row_stack(plan, A, tn), tn=tn,
-                             interpret=interpret)
+                             interpret=interpret,
+                             name="flashsketch_fwd_gather")
 
 
 def blockrow_pallas_gather(
@@ -778,7 +805,8 @@ def blockrow_pallas_gather(
         tn=tn)
     return _run_fused_gather(plan, kernel, _blockrow_table(plan), row_map,
                              _row_stack(plan, A, tn), tn=tn,
-                             interpret=interpret)
+                             interpret=interpret,
+                             name="flashsketch_blockrow_gather")
 
 
 def blockrow_pallas(
@@ -797,7 +825,7 @@ def blockrow_pallas(
         _fused_fwd_kernel, plan=plan, scale=scale, phi_fn=_phi_rows_tile
     )
     return _run_fused(plan, kernel, h_np, _stream(plan, A), n=n, tn=tn,
-                      interpret=interpret)
+                      interpret=interpret, name="flashsketch_blockrow")
 
 
 def flashsketch_pallas_partial(
@@ -869,8 +897,10 @@ def flashsketch_pallas_partial(
         scratch_shapes=[pltpu.VMEM((plan.Br, plan.Bc),
                                    plan.precision.compute_dtype)],
     )
-    out = pl.pallas_call(
-        kernel,
+    out = _launch(
+        "flashsketch_partial_blockrow" if rows_pattern
+        else "flashsketch_partial",
+        kernel, (jnp.asarray(tables, jnp.int32), operand),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (plan.kappa, out_blocks, plan.Br, n), jnp.float32),
@@ -879,7 +909,7 @@ def flashsketch_pallas_partial(
         # at j == 0; block tiles are independent.
         compiler_params=_compiler_params(
             ("parallel", "arbitrary", "arbitrary")),
-    )(jnp.asarray(tables, jnp.int32), operand)
+    )
     return out.reshape(plan.kappa, out_blocks * plan.Br, n)
 
 
@@ -946,7 +976,8 @@ def flashsketch_pallas_v1(
     phi_fn, tab = _fwd_phi_and_table(plan)
     kernel = functools.partial(_fwd_kernel_v1, plan=plan, scale=plan.scale,
                                phi_fn=phi_fn)
-    return _run_v1(plan, kernel, tab, A, n=n, tn=tn, interpret=interpret)
+    return _run_v1(plan, kernel, tab, A, n=n, tn=tn, interpret=interpret,
+                   name="flashsketch_fwd_v1")
 
 
 def flashsketch_transpose_pallas_v1(
@@ -963,7 +994,7 @@ def flashsketch_transpose_pallas_v1(
     kernel = functools.partial(_transpose_kernel_v1, plan=plan,
                                scale=plan.scale, phi_fn=phi_fn)
     return _run_v1(plan, kernel, tab, Y, n=n, tn=tn, interpret=interpret,
-                   transpose=True)
+                   transpose=True, name="flashsketch_transpose_v1")
 
 
 def blockrow_pallas_v1(
@@ -981,4 +1012,5 @@ def blockrow_pallas_v1(
     scale = plan.scale * math.sqrt(plan.d_pad / plan.k_pad)
     kernel = functools.partial(_fwd_kernel_v1, plan=plan, scale=scale,
                                phi_fn=_phi_rows_tile)
-    return _run_v1(plan, kernel, h_np, A, n=n, tn=tn, interpret=interpret)
+    return _run_v1(plan, kernel, h_np, A, n=n, tn=tn, interpret=interpret,
+                   name="flashsketch_blockrow_v1")

@@ -27,7 +27,11 @@ This module is the single place those decisions are made:
 ``lower`` is memoized process-wide, keyed like the tuner cache — the plan
 (which carries the shape class: d_pad/k_pad/M/Br/Bc/κ/s/dtype), the full
 spec, the backend tag, and ``tune.cache_generation()`` so freshly tuned
-winners invalidate stale records.
+winners invalidate stale records.  Every lookup counts
+``lowering.memo_hit`` or ``lowering.memo_miss`` in the counter registry
+(``repro.health.report``), which ``explain`` prints.  ``lower`` and
+``execute`` run under the program spans ``repro.lowering.lower`` and
+``repro.lowering.execute`` (``repro.tracing``).
 
 The downgrade ladder (each step recorded in ``Lowering.downgrade``):
 
@@ -54,6 +58,7 @@ from repro.health import report as health_report
 from repro.kernels import flashsketch as fsk
 from repro.kernels import ref as kref
 from repro.kernels import tune
+from repro.tracing import scope, scoped
 
 OPS = ("fwd", "transpose", "blockrow")
 SHARDS = ("none", "row", "col", "batch")
@@ -495,6 +500,7 @@ _CACHE_GEN: int = -1
 _MEMO_LOCK = threading.RLock()
 
 
+@scoped("repro.lowering.lower")
 def lower(plan: BlockPermPlan, spec: LaunchSpec) -> Lowering:
     """Resolve a launch request into a frozen ``Lowering`` record.
 
@@ -511,6 +517,8 @@ def lower(plan: BlockPermPlan, spec: LaunchSpec) -> Lowering:
             _LOWERING_CACHE.clear()
             _CACHE_GEN = gen
         hit = _LOWERING_CACHE.get((plan, spec, tune._backend_tag()))
+        health_report.record("lowering.memo_hit" if hit is not None
+                             else "lowering.memo_miss")
     if hit is not None:
         return hit
     hit = _lower(plan, spec, None)      # pure; safe outside the lock
@@ -542,9 +550,10 @@ def explain(plan: BlockPermPlan, spec: Optional[LaunchSpec] = None,
 
     The trace lists the dtype/impl resolution, every rejected tile
     candidate (with its VMEM footprint), any downgrade and its reason, the
-    padding plan, and the final record — plus the process-wide guard/health
-    counters (``repro.health.report``), so one explain shows both how the
-    launch resolves and what the guards have seen this process.
+    padding plan, and the final record — plus the process-wide counters
+    (``repro.health.report``: guard and recovery events, lowering-memo
+    hits and misses), so one explain shows both how the launch resolves
+    and what the process has recorded so far.
     """
     if spec is None:
         spec = LaunchSpec(**spec_kwargs)
@@ -556,7 +565,7 @@ def explain(plan: BlockPermPlan, spec: Optional[LaunchSpec] = None,
             f"tn={spec.tn}, dtype={spec.dtype!r}, gather={spec.gather}, "
             f"batch={spec.batch}, shard={spec.shard!r}x{spec.devices})")
     lines = [head] + ["  " + ln for ln in trace] + ["=> " + lw.describe()]
-    lines.append("health: " + health_report.summarize_counters())
+    lines.append("counters: " + health_report.summarize_counters())
     return "\n".join(lines)
 
 
@@ -610,6 +619,7 @@ _GATHER_KERNELS = {
 }
 
 
+@scoped("repro.lowering.execute")
 def execute(lw: Lowering, operand: jnp.ndarray,
             row_index: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Run a single-device ``Lowering`` on its operand.
@@ -651,17 +661,19 @@ def execute(lw: Lowering, operand: jnp.ndarray,
         return _ORACLES[lw.op](plan, _emulate_stream(plan, operand))
 
     if lw.gather_fused:
-        rmap = row_map_for(plan, row_index)
+        with scope("repro.lowering.row_map"):
+            rmap = row_map_for(plan, row_index)
         Y = _GATHER_KERNELS[lw.op](plan, operand, rmap, tn=lw.tn,
                                    interpret=lw.interpret)
         return Y[: plan.k, :n]
 
-    if lw.op == "transpose":
-        if operand.shape[0] != plan.k_pad:
-            operand = jnp.pad(
-                operand, ((0, plan.k_pad - operand.shape[0]), (0, 0)))
-    else:
-        operand = kref.pad_input(plan, operand)
+    with scope("repro.lowering.pad"):
+        if lw.op == "transpose":
+            if operand.shape[0] != plan.k_pad:
+                operand = jnp.pad(
+                    operand, ((0, plan.k_pad - operand.shape[0]), (0, 0)))
+        else:
+            operand = kref.pad_input(plan, operand)
 
     if lw.impl == "pallas_v1":
         # v1 computes in fp32; keep the plan's streaming-precision contract

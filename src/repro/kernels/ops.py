@@ -24,7 +24,8 @@ decision, padding — lives in ``kernels.lowering``.  Each call builds one
 trace-time safe) and runs ``lowering.execute`` on the operands; the
 ``custom_vjp`` wiring below is the only logic this module owns.  Inspect
 any launch decision with ``lowering.explain(plan, n=..., ...)`` or the
-``tools/explain_lowering.py`` CLI.
+``tools/explain_lowering.py`` CLI.  Each public entry point runs under a
+``repro.ops.<entry>`` program span and scope (``repro.tracing``).
 
 The VJP of ``Y = S A`` w.r.t. ``A`` is ``Sᵀ dY`` — the transpose kernel —
 so sketching composes with ``jax.grad`` (needed when the sketch sits inside
@@ -33,9 +34,9 @@ a training graph, e.g. sketched gradient compression with error feedback).
 Gather-fused path (the GraSS sparsify→sketch fusion): every forward entry
 point takes ``row_index=`` — a ``(plan.d,)`` int array of source rows — and
 computes ``Y = S @ A[row_index, :]`` in ONE kernel launch with no
-``A[row_index]`` intermediate (``sketch_apply_indexed`` is the underlying
-custom_vjp primitive; its VJP scatters ``Sᵀ dY`` back into the masked
-rows).  ``sketch_apply_batched`` folds a stack of matrices into the column
+``A[row_index]`` intermediate (``sketch_apply_indexed`` runs the
+underlying custom_vjp primitive; its VJP scatters ``Sᵀ dY`` back into the
+masked rows).  ``sketch_apply_batched`` folds a stack of matrices into the column
 axis of that same single launch, so a B-example batch of sparsified
 gradients is sketched at full tile width instead of B skinny launches.
 
@@ -59,6 +60,7 @@ from repro.core.blockperm import BlockPermPlan
 from repro.core.precision import fp32_matmul
 from repro.health import report as health_report
 from repro.kernels import lowering
+from repro.tracing import scoped
 
 Impl = Literal["auto", "pallas", "pallas_v1", "xla"]
 
@@ -83,6 +85,7 @@ def _sketch_apply_vjp(
     return _sketch_apply_impl(plan, A, impl, tn, dtype)
 
 
+@scoped("repro.ops.sketch_apply")
 def sketch_apply(
     plan: BlockPermPlan,
     A: jnp.ndarray,
@@ -144,6 +147,7 @@ def _sketch_apply_t_vjp(
     return _sketch_apply_t_impl(plan, Y, impl, tn, dtype)
 
 
+@scoped("repro.ops.sketch_apply_t")
 def sketch_apply_t(
     plan: BlockPermPlan,
     Y: jnp.ndarray,
@@ -219,6 +223,13 @@ def _sketch_apply_indexed_impl(plan, A, row_index, impl, tn, dtype):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3, 4, 5))
+def _sketch_apply_indexed_vjp(plan, A, row_index, impl, tn, dtype):
+    """custom_vjp core of ``sketch_apply_indexed`` (VJP scatters ``Sᵀ dY``
+    into the masked rows)."""
+    return _sketch_apply_indexed_impl(plan, A, row_index, impl, tn, dtype)
+
+
+@scoped("repro.ops.sketch_apply_indexed")
 def sketch_apply_indexed(
     plan: BlockPermPlan,
     A: jnp.ndarray,
@@ -252,7 +263,7 @@ def sketch_apply_indexed(
       ``(k, n)`` fp32 array.  Differentiable in ``A``: the VJP scatters
       ``Sᵀ dY`` into rows ``row_index`` of a zero ``(d_src, n)`` cotangent.
     """
-    return _sketch_apply_indexed_impl(plan, A, row_index, impl, tn, dtype)
+    return _sketch_apply_indexed_vjp(plan, A, row_index, impl, tn, dtype)
 
 
 def _indexed_fwd(plan, A, row_index, impl, tn, dtype):
@@ -268,9 +279,10 @@ def _indexed_bwd(plan, impl, tn, dtype, res, dY):
     return dA, None
 
 
-sketch_apply_indexed.defvjp(_indexed_fwd, _indexed_bwd)
+_sketch_apply_indexed_vjp.defvjp(_indexed_fwd, _indexed_bwd)
 
 
+@scoped("repro.ops.blockrow_apply")
 def blockrow_apply(
     plan: BlockPermPlan,
     A: jnp.ndarray,
@@ -349,6 +361,7 @@ def sketch_vectors(plan: BlockPermPlan, x: jnp.ndarray, impl: Impl = "auto",
     return Y.T.reshape(*x.shape[:-1], plan.k)
 
 
+@scoped("repro.ops.sketch_apply_batched")
 def sketch_apply_batched(
     plan: BlockPermPlan,
     A: jnp.ndarray,

@@ -15,7 +15,7 @@ def rng():
 
 @pytest.fixture(autouse=True)
 def _fresh_health_counters():
-    """Zero the process-global guard-event counters before every test, so
+    """Zero the process-global event counters before every test, so
     counter-delta assertions never depend on which tests ran earlier."""
     from repro.health import report
     report.reset_counters()

@@ -68,7 +68,7 @@ def test_global_counters_roundtrip():
     assert json.loads(report.counters_json()) == {"guard.test.failed": 2}
     assert "guard.test.failed=2" in report.summarize_counters()
     report.reset_counters()
-    assert report.summarize_counters() == "no guard events recorded"
+    assert report.summarize_counters() == "no events recorded"
 
 
 def test_policy_attempt_sequence_deterministic():
@@ -200,9 +200,9 @@ def test_vmem_overflow_forces_downgrade_and_counts():
     lw = lowering.lower(plan, spec)
     assert lw.downgrade and "vmem" in lw.downgrade
     assert report.counters().get("lowering.downgrade", 0) >= 1
-    # the downgrade shows up in explain() alongside the health section
+    # the downgrade shows up in explain() on its counters line
     txt = lowering.explain(plan, spec)
-    assert "lowering.downgrade" in txt and "health:" in txt
+    assert "lowering.downgrade" in txt and "counters:" in txt
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +393,7 @@ def test_explain_includes_health_counters():
     report.reset_counters()
     plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=4)
     txt = lowering.explain(plan, op="fwd", n=8, impl="pallas")
-    assert "health: no guard events recorded" in txt
+    assert "counters: no events recorded" in txt
     report.record("guard.finite.failed")
     txt = lowering.explain(plan, op="fwd", n=8, impl="pallas")
     assert "guard.finite.failed=1" in txt

@@ -13,9 +13,12 @@ where it came from, the VMEM footprint, the padding plan — plus the
 modeled TPU-v5e roofline of that same record (``engine.cost_of``).  CI
 runs this as a smoke step so the engine's public surface cannot rot.
 
+The trace ends with the process-wide counters (``repro.health.report``:
+guard and recovery events, lowering-memo hits and misses).
+
 ``--check-health`` additionally runs a tiny GUARDED solve on this shape
 (``sketch_precondition_lstsq(guard=True)``) and prints its HealthReport
-and the process-wide guard counters — exits non-zero if the guarded solve
+and the process-wide counters — exits non-zero if the guarded solve
 fails outright.  CI runs this too, so the guard layer's public surface is
 smoke-tested alongside the lowering trace.
 """
@@ -62,7 +65,7 @@ def main(argv=None) -> int:
                          "then show up as the resolved tile)")
     ap.add_argument("--check-health", action="store_true",
                     help="also run a tiny guarded solve on this shape and "
-                         "print its HealthReport + the guard counters "
+                         "print its HealthReport + the process counters "
                          "(nonzero exit if the guarded solve fails)")
     args = ap.parse_args(argv)
 
@@ -103,7 +106,7 @@ def main(argv=None) -> int:
 
 def _check_health(args) -> int:
     """Tiny guarded solve with this launch's κ/s/seed knobs; prints the
-    HealthReport and the process guard counters.  The problem shape is
+    HealthReport and the process-wide counters.  The problem shape is
     capped (the point is exercising the guard surface, not the launch
     size)."""
     import numpy as np
@@ -123,7 +126,7 @@ def _check_health(args) -> int:
     print(res.health.describe())
     print(f"converged={res.converged} relres={res.relres:.3g} "
           f"iterations={res.iterations}")
-    print("guard counters: " + report.summarize_counters(max_items=100))
+    print("counters: " + report.summarize_counters(max_items=100))
     if res.health.status == "failed" or not res.converged:
         print("health check FAILED", file=sys.stderr)
         return 1
