@@ -25,6 +25,7 @@ from repro.kernels.lowering import (  # noqa: F401
     Lowering,
     clear_lowering_cache,
     execute,
+    executor_cache_size,
     explain,
     lower,
     lowering_cache_size,

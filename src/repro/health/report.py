@@ -17,9 +17,11 @@ module is the vocabulary for the "detect" half:
     ``describe()`` / serializable via ``to_json()``.
   * the program's process-global **event counter registry** — the one
     place every layer counts events (``record``): guard verdicts and
-    recovery rungs, lowering downgrades, and the lowering memo's
+    recovery rungs, lowering downgrades, the lowering memo's
     ``lowering.memo_hit`` / ``lowering.memo_miss`` per ``lowering.lower``
-    call.  Long-running jobs export one counters JSON (``counters_json``);
+    call, and the executor memo's ``lowering.exec_memo_hit`` /
+    ``lowering.exec_memo_miss`` per concrete ``lowering.execute`` call.
+    Long-running jobs export one counters JSON (``counters_json``);
     ``engine.explain`` prints them on its ``counters:`` line alongside
     the lowering trace.  Program spans and scopes (time, not counts) are
     ``repro.tracing``'s.
@@ -170,7 +172,7 @@ class HealthReport:
 
 
 # ---------------------------------------------------------------------------
-# Process-global event counters (guards, recoveries, the lowering memo).
+# Process-global event counters (guards, recoveries, the lowering memos).
 # ---------------------------------------------------------------------------
 
 _LOCK = threading.Lock()
@@ -185,8 +187,10 @@ def record(event: str, n: int = 1, detail: Optional[str] = None) -> None:
     Event names are dotted paths: ``guard.<name>.<status>`` for guard
     verdicts, ``policy.<action>`` for escalation-ladder rungs,
     ``tune.cache_corrupt`` / ``factor.chol_downgrade`` / ``grass.quarantined``
-    for layer-specific recoveries, ``lowering.downgrade`` and
-    ``lowering.memo_hit`` / ``lowering.memo_miss`` for the lowering engine.
+    for layer-specific recoveries, ``lowering.downgrade``,
+    ``lowering.memo_hit`` / ``lowering.memo_miss`` and
+    ``lowering.exec_memo_hit`` / ``lowering.exec_memo_miss`` for the
+    lowering engine.
     """
     with _LOCK:
         _COUNTERS[event] = _COUNTERS.get(event, 0) + n

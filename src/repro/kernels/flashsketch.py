@@ -549,8 +549,12 @@ def _io_specs(transpose: bool):
 
 def _launch(name: str, kernel, args, **call_kwargs):
     """One ``pallas_call`` launch named ``name``, under the program span
-    and scope ``repro.kernel.<name>``: in an eager call the span covers the
-    kernel body's trace, its lowering, the program load and the enqueue."""
+    and scope ``repro.kernel.<name>``.  This runs while JAX traces the
+    launch (in a jitted caller, or the first eager call of a record, which
+    builds its memoized executor), so the span covers the kernel body's
+    trace.  A warm eager call never reaches it: ``lowering.execute`` opens
+    the same span around the compiled executor, so there it covers only
+    the enqueue."""
     with scope(f"repro.kernel.{name}"):
         return pl.pallas_call(kernel, name=name, **call_kwargs)(*args)
 

@@ -33,6 +33,21 @@ winners invalidate stale records.  Every lookup counts
 ``execute`` run under the program spans ``repro.lowering.lower`` and
 ``repro.lowering.execute`` (``repro.tracing``).
 
+``execute`` on concrete arrays (no ``jax.core.Tracer`` among the operand
+and row index) runs a compiled executor from a second process-wide memo:
+``jax.jit`` of the launch body (validation, row map, pad, stream cast,
+kernel, slice) closed over the record, keyed by the ``Lowering`` and the
+operand's and row index's shape and dtype.  A warm eager call is then one
+jit dispatch of one compiled program — no trace of the kernel body, no
+Mosaic lowering, no program load — under the span ``repro.kernel.<name>``
+of the kernel it launches.  Each concrete call counts
+``lowering.exec_memo_hit`` or ``lowering.exec_memo_miss``;
+``executor_cache_size`` reports the memo's size, and it is flushed with
+the lowering memo (``clear_lowering_cache`` and the tuner-generation
+flush).  Traced calls (under ``jit``, ``grad``, ``scan``, ``shard_map``)
+and the ``xla`` oracle run the body inline, as part of the caller's
+program.
+
 The downgrade ladder (each step recorded in ``Lowering.downgrade``):
 
   1. ``pallas`` + gather, fused scratch over budget → materialize the
@@ -46,7 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -498,6 +513,10 @@ _CACHE_GEN: int = -1
 # workers lower concurrently, and an unguarded flush racing an insert can
 # resurrect a stale-tile record or die iterating a resizing dict.
 _MEMO_LOCK = threading.RLock()
+# Compiled executors of concrete ``execute`` calls, keyed by (record,
+# operand shape/dtype, row-index shape/dtype).  The record is in the key,
+# so an entry never serves another tile; flushed with the memo above.
+_EXEC_MEMO: Dict[Tuple, Callable] = {}
 
 
 @scoped("repro.lowering.lower")
@@ -515,6 +534,7 @@ def lower(plan: BlockPermPlan, spec: LaunchSpec) -> Lowering:
         gen = tune.cache_generation()
         if gen != _CACHE_GEN:
             _LOWERING_CACHE.clear()
+            _EXEC_MEMO.clear()
             _CACHE_GEN = gen
         hit = _LOWERING_CACHE.get((plan, spec, tune._backend_tag()))
         health_report.record("lowering.memo_hit" if hit is not None
@@ -531,13 +551,21 @@ def lower(plan: BlockPermPlan, spec: LaunchSpec) -> Lowering:
 
 
 def clear_lowering_cache() -> None:
+    """Empty the lowering memo and the compiled executors built from it."""
     with _MEMO_LOCK:
         _LOWERING_CACHE.clear()
+        _EXEC_MEMO.clear()
 
 
 def lowering_cache_size() -> int:
     with _MEMO_LOCK:
         return len(_LOWERING_CACHE)
+
+
+def executor_cache_size() -> int:
+    """Number of compiled executors memoized by concrete ``execute`` calls."""
+    with _MEMO_LOCK:
+        return len(_EXEC_MEMO)
 
 
 def explain(plan: BlockPermPlan, spec: Optional[LaunchSpec] = None,
@@ -551,9 +579,9 @@ def explain(plan: BlockPermPlan, spec: Optional[LaunchSpec] = None,
     The trace lists the dtype/impl resolution, every rejected tile
     candidate (with its VMEM footprint), any downgrade and its reason, the
     padding plan, and the final record — plus the process-wide counters
-    (``repro.health.report``: guard and recovery events, lowering-memo
-    hits and misses), so one explain shows both how the launch resolves
-    and what the process has recorded so far.
+    (``repro.health.report``: guard and recovery events, lowering- and
+    executor-memo hits and misses), so one explain shows both how the
+    launch resolves and what the process has recorded so far.
     """
     if spec is None:
         spec = LaunchSpec(**spec_kwargs)
@@ -619,10 +647,28 @@ _GATHER_KERNELS = {
 }
 
 
+def _kernel_name(lw: Lowering) -> str:
+    """The ``pallas_call`` name of the kernel a single-device Pallas
+    record launches (its span is ``repro.kernel.<name>``)."""
+    name = {"fwd": "flashsketch_fwd", "transpose": "flashsketch_transpose",
+            "blockrow": "flashsketch_blockrow"}[lw.op]
+    if lw.gather_fused:
+        return name + "_gather"
+    return name + "_v1" if lw.impl == "pallas_v1" else name
+
+
+def _aval_key(x) -> Optional[Tuple]:
+    return None if x is None else (x.shape, x.dtype)
+
+
 @scoped("repro.lowering.execute")
 def execute(lw: Lowering, operand: jnp.ndarray,
             row_index: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Run a single-device ``Lowering`` on its operand.
+
+    Concrete Pallas calls run a compiled executor memoized per record and
+    operand shape/dtype (module docstring); traced calls and the ``xla``
+    oracle run the launch body inline.
 
     Args:
       lw: the record from ``lower`` (must have ``shard == "none"`` — the
@@ -640,6 +686,33 @@ def execute(lw: Lowering, operand: jnp.ndarray,
         raise ValueError(
             f"execute() runs single-device lowerings; shard={lw.shard!r} "
             f"records are executed by repro.distributed inside shard_map")
+    if lw.impl == "xla" or isinstance(operand, jax.core.Tracer) \
+            or isinstance(row_index, jax.core.Tracer):
+        return _launch_body(lw, operand, row_index)
+    key = (lw, _aval_key(operand), _aval_key(row_index))
+    with _MEMO_LOCK:
+        exe = _EXEC_MEMO.get(key)
+        health_report.record("lowering.exec_memo_hit" if exe is not None
+                             else "lowering.exec_memo_miss")
+    if exe is None:
+        # the first call traces the body (its spans nest here, as in a
+        # traced call), compiles it and runs it; memoize once it succeeded
+        def sketch_launch(operand, row_index):
+            return _launch_body(lw, operand, row_index)
+        exe = jax.jit(sketch_launch)
+        out = exe(operand, row_index)
+        with _MEMO_LOCK:
+            _EXEC_MEMO[key] = exe
+        return out
+    with scope(f"repro.kernel.{_kernel_name(lw)}"):
+        return exe(operand, row_index)
+
+
+def _launch_body(lw: Lowering, operand: jnp.ndarray,
+                 row_index: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """``execute``'s launch: validation, row map, pad, stream cast,
+    kernel and slice — traced into the caller's program or into a memoized
+    executor."""
     plan = lw.plan
     if lw.gather:
         if row_index is None:

@@ -21,8 +21,9 @@ Every entry point here is a THIN shell: all resolution — impl dispatch and
 downgrades, tile selection, VMEM budgeting, the gather-fuse-or-materialize
 decision, padding — lives in ``kernels.lowering``.  Each call builds one
 ``lowering.LaunchSpec``, resolves it with ``lowering.lower`` (memoized,
-trace-time safe) and runs ``lowering.execute`` on the operands; the
-``custom_vjp`` wiring below is the only logic this module owns.  Inspect
+trace-time safe) and runs ``lowering.execute`` on the operands (an eager
+call runs the record's memoized compiled executor); the ``custom_vjp``
+wiring below is the only logic this module owns.  Inspect
 any launch decision with ``lowering.explain(plan, n=..., ...)`` or the
 ``tools/explain_lowering.py`` CLI.  Each public entry point runs under a
 ``repro.ops.<entry>`` program span and scope (``repro.tracing``).

@@ -71,9 +71,17 @@ def _host_spans(trace_dir):
     return spans
 
 
-def test_eager_call_records_nested_host_spans(plan, tmp_path):
+@pytest.mark.parametrize("memo", ["miss", "hit"])
+def test_eager_call_records_nested_host_spans(plan, tmp_path, memo):
+    """An eager call nests ops -> execute -> the kernel's launch span, with
+    the lowering before the launch.  A miss (fresh memos) builds the
+    tables and traces the launch inside execute; a hit runs the memoized
+    executor under the kernel span alone, with no tables span."""
     A = _operand(plan)
-    ops.sketch_apply(plan, A, "pallas").block_until_ready()   # warm
+    if memo == "miss":
+        lowering.clear_lowering_cache()
+    else:
+        ops.sketch_apply(plan, A, "pallas").block_until_ready()   # warm
     with jax.profiler.trace(str(tmp_path)):
         ops.sketch_apply(plan, A, "pallas").block_until_ready()
     spans = _host_spans(tmp_path)
@@ -83,7 +91,11 @@ def test_eager_call_records_nested_host_spans(plan, tmp_path):
     assert outer[0] <= mid[0] <= inner[0] < inner[1] <= mid[1] <= outer[1]
     (lower,) = spans["repro.lowering.lower"]
     assert outer[0] <= lower[0] < lower[1] <= mid[0]
-    assert len(spans["repro.kernel.tables"]) == 1
+    if memo == "miss":
+        (tables,) = spans["repro.kernel.tables"]
+        assert mid[0] <= tables[0] < tables[1] <= inner[0]
+    else:
+        assert "repro.kernel.tables" not in spans
 
 
 def test_memo_counters_count_lookups(plan):
