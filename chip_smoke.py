@@ -304,9 +304,10 @@ def phase_grass(key):
         warm_s = time.perf_counter() - t0
         unfused = GrassPipeline(dataclasses.replace(cfg, fused=False),
                                 params).featurize(xs, ys)
-        grads = jax.jit(jax.vmap(lambda x, y: pipe._gfn(params, x, y)))(
+        src = pipe.source
+        grads = jax.jit(jax.vmap(lambda x, y: src.grad_fn(params, x, y)))(
             jnp.asarray(xs), jnp.asarray(ys))
-        want = ref.flashsketch_ref(pipe.sketch.plan, grads[:, pipe.mask].T).T
+        want = ref.flashsketch_ref(pipe.sketch.plan, grads[:, src.mask].T).T
     exact = bool(np.array_equal(np.asarray(feats), np.asarray(unfused)))
     err, scale = _max_err(feats, want)
     atol = pipe.sketch.plan.precision.exactness_atol

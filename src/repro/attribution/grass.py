@@ -7,6 +7,12 @@ the sparsify mask's coordinates directly out of the stacked gradients — no
 ``grads[:, mask]`` intermediate, no per-example launches.  Any variant from
 ``repro.core.variants`` can be swapped in for the Pareto benchmarks
 (families without a fused kernel fall back to a materializing gather).
+
+Where the gradient comes from is a ``GradientSource``: the MLP's flattened
+margin gradient (``MarginGradients``, the default) or a factorized
+per-layer capture whose rows are already sparsified
+(``attribution.moonlight.MoonlightGradients``).  The scan, the quarantine,
+the sketch step and the program scopes are one body for every source.
 """
 from __future__ import annotations
 
@@ -46,6 +52,53 @@ def _flat_grad_fn(params):
         g = jax.grad(lambda pp: mlp_lib.margin_output(pp, x[None], y[None])[0])(p)
         return jnp.concatenate([a.reshape(-1) for a in jax.tree.leaves(g)])
     return gfn
+
+
+class GradientSource:
+    """What ``GrassPipeline.featurize`` asks of a per-example gradient.
+
+    ``dim`` is the number of coordinates the sketch sees per example;
+    ``gathers`` says whether ``sketch_rows`` gathers them out of wider rows
+    (the fused gather kernel) or sketches the rows as they are.
+    """
+
+    dim: int
+    gathers: bool = False
+
+    def rows(self, p, xb, yb):
+        """``(rows (c, D), stats)`` for one chunk: each example's gradient
+        row, and per-example statistics (a pytree of arrays with leading
+        axis ``c``, summed over a call and handed to ``note``)."""
+        raise NotImplementedError
+
+    def sketch_rows(self, sketch: SketchBase, rows) -> jnp.ndarray:
+        """``(c, k)`` features of a chunk's (quarantined) rows."""
+        return sketch.apply(rows.T).T
+
+    def note(self, stats) -> None:
+        """Host side, after a call: the call's summed ``stats``."""
+
+
+class MarginGradients(GradientSource):
+    """The MLP's flattened per-example margin gradient (``mlp.py``): all
+    ``d_total`` parameters, of which the sparsify mask keeps
+    ``min(cfg.sparse_dim, d_total)``; the sketch gathers them in-kernel
+    (``cfg.fused``) or from a materialized ``grads[:, mask]``."""
+
+    def __init__(self, params, cfg: GrassPipelineConfig):
+        self.d_total = sum(p.size for p in jax.tree.leaves(params))
+        self.dim = min(cfg.sparse_dim, self.d_total)
+        self.mask = sparsify_mask(self.d_total, self.dim, cfg.seed)
+        self.gathers = cfg.fused
+        self.grad_fn = _flat_grad_fn(params)
+
+    def rows(self, p, xb, yb):
+        return jax.vmap(lambda x, y: self.grad_fn(p, x, y))(xb, yb), ()
+
+    def sketch_rows(self, sketch, grads):
+        if self.gathers:
+            return sketch.apply_gather(grads.T, self.mask).T
+        return sketch.apply(grads[:, self.mask].T).T
 
 
 def sparsify_mask(d_total: int, d_keep: int, seed: int) -> jnp.ndarray:
@@ -94,28 +147,27 @@ class GrassPipeline:
     ``repro.grass.sketch`` (``repro.tracing``), so a device trace splits
     the scan's time by step; ``featurize`` runs under the host span
     ``repro.grass.featurize``.
+
+    Source: ``source`` supplies each chunk's gradient rows and how they
+    are sketched (``GradientSource``); the default is the MLP's margin
+    gradient of ``params`` (``MarginGradients``), the one that
+    ``cfg.sparse_dim`` and ``cfg.fused`` apply to.
     """
 
     def __init__(self, cfg: GrassPipelineConfig, params, mesh=None,
-                 shard_axis: str = "data"):
+                 shard_axis: str = "data",
+                 source: Optional[GradientSource] = None):
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
         self.shard_axis = shard_axis
         self.quarantined = 0           # rows zeroed across all featurize calls
-        d_total = sum(p.size for p in jax.tree.leaves(params))
-        self.d_total = d_total
-        d_keep = min(cfg.sparse_dim, d_total)
-        self.mask = sparsify_mask(d_total, d_keep, cfg.seed)
+        self.source = source if source is not None else \
+            MarginGradients(params, cfg)
         self.sketch: SketchBase = make_sketch(
-            cfg.sketch_family, d_keep, cfg.sketch_dim, seed=cfg.seed,
+            cfg.sketch_family, self.source.dim, cfg.sketch_dim, seed=cfg.seed,
             **dict(cfg.sketch_kwargs))
-        self._gfn = _flat_grad_fn(params)
-
-        def sketch_chunk(grads):                    # (c, D) -> (c, k)
-            if cfg.fused:
-                return self.sketch.apply_gather(grads.T, self.mask).T
-            return self.sketch.apply(grads[:, self.mask].T).T
+        src = self.source
 
         def featurize(p, xs, ys):
             b = xs.shape[0]
@@ -137,24 +189,23 @@ class GrassPipeline:
             yc = ys.reshape((n_chunks, c) + ys.shape[1:])
 
             def chunk_feats(p_, xy):
-                """One chunk: vmapped per-example grads -> quarantine ->
-                fused sketch.  The SAME body drives both branches, so
-                sharded features cannot drift from single-device ones.
-                Non-finite gradient rows are zeroed (quarantined) before
-                the sketch and flagged — jit-compatible (a where, not a
-                branch)."""
+                """One chunk: the source's gradient rows -> quarantine ->
+                sketch.  The SAME body drives both branches, so sharded
+                features cannot drift from single-device ones.  Non-finite
+                gradient rows are zeroed (quarantined) before the sketch
+                and flagged — jit-compatible (a where, not a branch)."""
                 xb, yb = xy
                 with scope("repro.grass.grads"):
-                    grads = jax.vmap(lambda x, y: self._gfn(p_, x, y))(xb, yb)
+                    grads, stats = src.rows(p_, xb, yb)
                 with scope("repro.grass.quarantine"):
                     ok = jnp.all(jnp.isfinite(grads), axis=1)
                     grads = jnp.where(ok[:, None], grads, 0.0)
                 with scope("repro.grass.sketch"):
-                    feats = sketch_chunk(grads)
-                return feats, ~ok                   # (c, k), (c,) per chunk
+                    feats = src.sketch_rows(self.sketch, grads)
+                return feats, ~ok, stats    # (c, k), (c,), (c, ...) a chunk
 
             if mesh is None:
-                _, (feats, bad) = jax.lax.scan(
+                _, (feats, bad, stats) = jax.lax.scan(
                     lambda car, xy: (car, chunk_feats(p, xy)), 0, (xc, yc))
             else:
                 from jax.sharding import PartitionSpec as P
@@ -165,17 +216,19 @@ class GrassPipeline:
                         0, (xcl, ycl))
                     return fb
 
-                feats, bad = jax.shard_map(
+                feats, bad, stats = jax.shard_map(
                     scan_local, mesh=mesh,
                     in_specs=(P(), P(shard_axis), P(shard_axis)),
-                    out_specs=(P(shard_axis), P(shard_axis)),
+                    out_specs=P(shard_axis),
                     check_vma=False,
                 )(p, xc, yc)
-            # padded tail rows are sliced off BEFORE the bad-row count, so
-            # a quarantined example is never double-counted via its padding
-            # copies
+            # padded tail rows are sliced off BEFORE the bad-row count and
+            # the stats' sum, so a padding copy of an example counts nothing
+            stats = jax.tree.map(
+                lambda a: a.reshape((n_chunks * c,) + a.shape[2:])[:b]
+                .sum(axis=0), stats)
             return (feats.reshape(n_chunks * c, -1)[:b],
-                    bad.reshape(n_chunks * c)[:b])
+                    bad.reshape(n_chunks * c)[:b], stats)
 
         self._featurize = jax.jit(featurize)
 
@@ -187,9 +240,13 @@ class GrassPipeline:
         gradient contained any non-finite entry come back as zeros and are
         added to ``.quarantined`` / the ``grass.quarantined`` counter.
         """
-        feats, bad = self._featurize(self.params, xs, ys)
-        self._note_quarantine(bad)
+        feats, bad, stats = self._featurize(self.params, xs, ys)
+        self._note(bad, stats)
         return feats
+
+    def _note(self, bad, stats) -> None:
+        self._note_quarantine(bad)
+        self.source.note(stats)
 
     def _note_quarantine(self, bad) -> None:
         nbad = int(np.asarray(bad).sum())
@@ -218,7 +275,7 @@ class GrassPipeline:
         XLA ops).  Inspect with ``.describe()`` or price it with
         ``repro.engine.cost_of``."""
         return self.sketch.lowering_for(max(1, self.cfg.chunk),
-                                        gather=self.cfg.fused)
+                                        gather=self.source.gathers)
 
     # ---------------------------------------------------------------- cache
     def build_cache(self, x_train, y_train, batch: int = 256) -> Tuple[jnp.ndarray, float]:
@@ -233,10 +290,10 @@ class GrassPipeline:
             xb = x_train[i:i + batch]
             yb = y_train[i:i + batch]
             t0 = time.perf_counter()
-            f, bad = self._featurize(self.params, xb, yb)
+            f, bad, stats = self._featurize(self.params, xb, yb)
             f.block_until_ready()
             t += time.perf_counter() - t0
-            self._note_quarantine(bad)
+            self._note(bad, stats)
             feats.append(f)
         return jnp.concatenate(feats, axis=0), t
 

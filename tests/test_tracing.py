@@ -56,6 +56,45 @@ def test_featurize_carries_the_grass_scopes():
         assert name in text, name
 
 
+def _moonlight_pipe():
+    from repro.attribution import moonlight as ML
+    cfg = ML.MoonlightConfig(
+        hidden=32, heads=2, qk_nope=8, qk_rope=4, v_head=8, kv_lora=16,
+        dense_width=32, expert_width=8, experts=8, top_k=2, held=(0, 1),
+        layers=2, vocab=64)
+    params = ML.init_params(cfg, jax.random.PRNGKey(0))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (3, 8), 0, cfg.vocab)
+    pipe = GrassPipeline(GrassPipelineConfig(sketch_dim=64, chunk=2), params,
+                         source=ML.MoonlightGradients(cfg))
+    return pipe, params, ids
+
+
+def test_moonlight_featurize_carries_the_model_scopes():
+    """The factorized source's steps are scoped inside the GraSS steps:
+    attention, routing and experts (forward and backward) and the factor
+    products under ``repro.grass.grads``."""
+    pipe, params, ids = _moonlight_pipe()
+    text = pipe._featurize.lower(params, ids, ids).as_text(debug_info=True)
+    for name in ("repro.grass.grads", "repro.grass.quarantine",
+                 "repro.grass.sketch", "repro.mla.attention",
+                 "repro.moe.route", "repro.moe.experts",
+                 "repro.grass.factors"):
+        assert name in text, name
+    assert "repro.grass.grads/repro.grass.factors" in text
+
+
+def test_moonlight_featurize_records_expert_tokens():
+    pipe, params, ids = _moonlight_pipe()
+    report.reset_counters()
+    pipe.featurize(ids, ids)
+    seen = pipe.source.expert_tokens
+    assert seen.shape == (1, 2)
+    assert report.counters()["grass.expert_tokens"] == int(seen.sum()) > 0
+    (event, detail), = [e for e in report.recent_events()
+                        if e[0] == "grass.expert_tokens"]
+    assert f"max {int(seen.max())}" in detail and "mean" in detail
+
+
 def _host_spans(trace_dir):
     from jax.profiler import ProfileData
     (path,) = Path(trace_dir).rglob("*.xplane.pb")
