@@ -100,6 +100,12 @@ GLOBAL_FAMILY_TAG = 0x610B
 # pipelined input blocks are replaced by one (κ·B_c, tn) scratch buffer.
 GATHER_VARIANTS = ("fwd_gather", "blockrow_gather")
 
+# The input-stationary forward (permutation wiring only): one pipelined
+# view of the input, each block read once against its κ Φ tiles stacked,
+# with write-once per-level slots and an (M, B_r, tn) output block
+# resident across the input blocks of a column tile.
+STATIONARY_VARIANT = "fwd_stationary"
+
 
 def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
     """VMEM bytes of a (rows, cols) array as the chip lays it out: rows
@@ -112,7 +118,8 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 def fused_variant_bytes(kappa: int, Br: int, Bc: int, tn: int,
                         itemsize: int = 4, variant: str = "fwd",
                         phi_itemsize: Optional[int] = None,
-                        gather_mask: bool = False) -> int:
+                        gather_mask: bool = False, *,
+                        M: Optional[int] = None) -> int:
     """VMEM the chip's compiler allocates for one launch of a kernel
     variant at tile width ``tn``: the (κ, Br, Bc) Φ scratch, the
     double-buffered pipelined input and output blocks (or, for the
@@ -128,12 +135,28 @@ def fused_variant_bytes(kappa: int, Br: int, Bc: int, tn: int,
     policies (Φ is held in the compute dtype the fp8 stream is upcast
     to, ``precision.compute_itemsize``) — defaults to ``itemsize``.
     ``gather_mask``: the gather kernel zeroes padded rows (plan ``d <
-    d_pad``), one more (ch, tn) temporary."""
+    d_pad``), one more (ch, tn) temporary.  ``M`` (the plan's output blocks) is
+    required for ``STATIONARY_VARIANT``, whose slots and output block
+    span every output block; no variant's working set depends on n."""
     ci = itemsize if phi_itemsize is None else phi_itemsize
     ch = phi_chunk(Bc)
-    phi = kappa * _tile_bytes(Br, Bc, ci)
     # Φ build: iota, compare, select and accumulator at (Br, ch) fp32
     build = 4 * _tile_bytes(Br, ch, 4)
+    if variant == STATIONARY_VARIANT:
+        if M is None:
+            raise ValueError(f"{variant!r} needs M, the plan's output blocks")
+        phi = _tile_bytes(kappa * Br, Bc, ci)
+        # one double-buffered input block; the (κ, M, Br, tn) fp32 slots
+        # and the double-buffered (M, Br, tn) output block
+        ins = 2 * _tile_bytes(Bc, tn, itemsize)
+        out = (kappa + 2) * M * _tile_bytes(Br, tn, 4)
+        # the loaded (ch, tn) chunk, its compute-dtype copy and fp32
+        # pieces, the (κ·Br, tn) fp32 accumulators, the fold's (Br, tn)
+        temps = (3 * _tile_bytes(ch, tn, 4)
+                 + 4 * _tile_bytes(kappa * Br, tn, 4)
+                 + 3 * _tile_bytes(Br, tn, 4))
+        return phi + ins + out + build + temps
+    phi = kappa * _tile_bytes(Br, Bc, ci)
     if variant == "transpose":
         ins = 2 * kappa * _tile_bytes(Br, tn, itemsize)
         out = 2 * _tile_bytes(Bc, tn, 4)
@@ -159,7 +182,10 @@ def fused_variant_bytes(kappa: int, Br: int, Bc: int, tn: int,
 def fused_working_set_bytes(kappa: int, Br: int, Bc: int, tn: int,
                             itemsize: int = 4,
                             phi_itemsize: Optional[int] = None) -> int:
-    """Worst case of ``fused_variant_bytes`` over all kernel variants."""
+    """Worst case of ``fused_variant_bytes`` over the output-stationary
+    forward and the transpose: the kernels every plan must be able to run.
+    (The gather and input-stationary variants fall back to them when they
+    do not fit.)"""
     return max(
         fused_variant_bytes(kappa, Br, Bc, tn, itemsize, v, phi_itemsize)
         for v in ("fwd", "transpose")

@@ -1,6 +1,10 @@
 """FLASHSKETCH v2 Pallas/TPU kernel suite (paper §5, adapted per DESIGN.md §2).
 
-v2 (default) — fused-κ single-write formulation:
+v2 (default) has two forward formulations, which ``kernels.lowering``
+picks between (``Lowering.stationary``): output-stationary (below; every
+plan) and input-stationary (permutation-wired plans whose slots fit).
+
+v2 output-stationary — fused-κ single-write formulation:
 
   * Grid ``(M, n/T_n)`` with the column-tile axis ``j`` **innermost**.
     Program ``(g, j)`` owns output tile ``Y[g·B_r:(g+1)B_r, j·T_n:(j+1)T_n]``
@@ -28,6 +32,29 @@ v2 (default) — fused-κ single-write formulation:
     column count, and every launch fits ``VMEM_BUDGET_BYTES``, which is
     also passed to Mosaic as the scoped-VMEM limit.  Φ is built in
     lane-aligned column chunks so its hash temporaries stay small.
+
+v2 input-stationary (``flashsketch_pallas_stationary``) — for a wiring
+that is a permutation per level (the ``blockperm`` family):
+
+  * Grid ``(n/T_n, M)`` over column tiles ``j`` (outer) and input blocks
+    ``h`` (inner), with ONE pipelined view of A: every ``(B_c, T_n)``
+    block is read from HBM once, not κ times.
+  * Input block ``h`` feeds exactly one output block per level,
+    ``g_ℓ = π_ℓ⁻¹(h)``.  Each program builds the κ tiles ``Φ_{g_ℓ,h}``
+    into one ``(κ·B_r, B_c)`` VMEM stack (the same hashes, so the same Φ)
+    and contracts it against the block in one dot per chunk: each A chunk
+    enters the MXU once, against κ·B_r rows.  Φ is rebuilt once per
+    column tile; the engine picks the widest tile that fits, one tile at
+    the benchmark cells' n.
+  * Level ℓ's ``(B_r, T_n)`` result goes to a write-once fp32 slot
+    ``(κ, M, B_r, T_n)`` in VMEM; at the last input block of a column
+    tile the slots are folded in ℓ order and scaled — the
+    output-stationary kernel's order, so the two are bit-identical
+    wherever the MXU's rows are independent of how many rows share a
+    dot.  The ``(M, B_r, T_n)`` output block stays resident across ``h``
+    and is written back once per column tile.  The working set depends
+    on the tile and the plan, not on n; column tiles are independent and
+    may be megacore-partitioned, input blocks run in order.
 
 v2-gather (``*_gather``) — the same fused-κ formulation with the input row
 gather folded INTO the kernel: the operand stays in HBM (``pl.ANY``)
@@ -331,6 +358,41 @@ def _fused_fwd_kernel(tab_ref, *refs, plan: BlockPermPlan, scale, phi_fn):
     o_ref[...] = _fused_sum(
         phi_ref, lambda ell, c0, ch: a_refs[ell][pl.ds(c0, ch), :],
         plan, o_ref.shape) * scale
+
+
+def _stationary_fwd_kernel(tab_ref, a_ref, o_ref, phi_ref, slot_ref, *,
+                           plan: BlockPermPlan, scale, phi_fn):
+    """Input-stationary forward body: program ``(j, h)`` contracts input
+    block ``A[h·B_c:…, j·tn:…]``, fetched once, against the κ tiles
+    ``Φ_{g_ℓ,h}`` (``g_ℓ = π_ℓ⁻¹(h)``, ``tab_ref[ℓ, h]``) stacked as one
+    ``(κ·B_r, B_c)`` scratch, in one dot per chunk.  Level ℓ's rows land
+    in the write-once slot ``slot_ref[ℓ, g_ℓ]`` (π_ℓ is a permutation, so
+    every slot is written exactly once per column tile); at the last
+    input block the slots are folded in ℓ order and scaled —
+    ``_fused_sum``'s order, so the result is the output-stationary
+    kernel's bit for bit wherever a dot's rows do not depend on how many
+    rows it has (on a v5e they do not; the CPU interpreter's 512-deep
+    chunks do)."""
+    h = pl.program_id(1)
+    Br, tn = plan.Br, a_ref.shape[1]
+    for ell in range(plan.kappa):
+        _build_phi(phi_ref.at[pl.ds(ell * Br, Br)], plan, phi_fn,
+                   tab_ref[ell, h], h)
+    y = _phi_dot(phi_ref, lambda c0, ch: a_ref[pl.ds(c0, ch), :],
+                 plan.Bc, (plan.kappa * Br, tn))
+    for ell in range(plan.kappa):
+        slot_ref[ell, tab_ref[ell, h]] = y[ell * Br:(ell + 1) * Br]
+
+    @pl.when(h == pl.num_programs(1) - 1)
+    def _fold():
+        def body(g, carry):
+            acc = slot_ref[0, g]
+            for ell in range(1, plan.kappa):
+                acc = acc + slot_ref[ell, g]
+            o_ref[g] = acc * scale
+            return carry
+
+        jax.lax.fori_loop(0, plan.M, body, 0)
 
 
 def _fused_transpose_kernel(tab_ref, *refs, plan: BlockPermPlan, scale,
@@ -646,6 +708,41 @@ def _run_fused(plan, kernel, tab, operand, n, tn, interpret, transpose=False,
     return out if transpose else out.reshape(plan.k_pad, n)
 
 
+def _run_stationary(plan, kernel, tab, operand, n, tn, interpret, *,
+                    name: str):
+    """Input-stationary launcher: grid (n/tn, M) over column tiles ``j``
+    (outer) and input blocks ``h`` (inner), ONE pipelined view of the
+    operand, so each (B_c, tn) block is read from HBM once.  The stacked
+    Φ scratch ``(κ·B_r, B_c)`` is built by every program; the ``(κ, M,
+    B_r, tn)`` fp32 slots and the ``(M, B_r, tn)`` output block are
+    resident across ``h``.  ``n`` may be ragged: the edge tile rides the
+    Pallas machinery as in ``_run_fused``.  Returns the (k_pad, n)
+    forward."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(-(-n // tn), plan.M),
+        in_specs=[_d_spec(plan, tn, lambda j, h, tab_ref: (h, j))],
+        out_specs=pl.BlockSpec((plan.M, plan.Br, tn),
+                               lambda j, h, tab_ref: (0, 0, j)),
+        scratch_shapes=[
+            pltpu.VMEM((plan.kappa * plan.Br, plan.Bc),
+                       plan.precision.compute_dtype),       # stacked Φ
+            pltpu.VMEM((plan.kappa, plan.M, plan.Br, tn),
+                       jnp.float32),                        # slots
+        ],
+    )
+    out = _launch(
+        name, kernel, (jnp.asarray(tab), operand),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((plan.M, plan.Br, n), jnp.float32),
+        interpret=interpret,
+        # h must run in order per j (the slots are folded at the last h);
+        # column tiles are independent and may be megacore-partitioned.
+        compiler_params=_compiler_params(("parallel", "arbitrary")),
+    )
+    return out.reshape(plan.k_pad, n)
+
+
 def _run_fused_gather(plan, kernel, tab, row_map, rows, tn, interpret, *,
                       name: str):
     """Gather launcher: grid (M, n_pad/tn) over the ``_row_stack`` operand
@@ -733,6 +830,28 @@ def flashsketch_pallas(
     )
     return _run_fused(plan, kernel, tab, _stream(plan, A), n=n, tn=tn,
                       interpret=interpret, name="flashsketch_fwd")
+
+
+def flashsketch_pallas_stationary(
+    plan: BlockPermPlan,
+    A: jnp.ndarray,
+    *,
+    tn: int = 128,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Y = S A via the input-stationary kernel: each block of A is read
+    once, against its κ Φ tiles stacked.  Permutation wiring only (a
+    ``blockperm``-family plan); bit-identical to ``flashsketch_pallas``.
+    A must be (d_pad, n); n may be ragged."""
+    d_pad, n = A.shape
+    assert d_pad == plan.d_pad, (d_pad, plan.d_pad)
+    assert not plan.is_global, plan.family
+    # the transpose's table: g = π_ℓ⁻¹(h), the one output block per level
+    phi_fn, tab = _transpose_phi_and_table(plan)
+    kernel = functools.partial(_stationary_fwd_kernel, plan=plan,
+                               scale=plan.scale, phi_fn=phi_fn)
+    return _run_stationary(plan, kernel, tab, _stream(plan, A), n=n, tn=tn,
+                           interpret=interpret, name="flashsketch_fwd")
 
 
 def flashsketch_transpose_pallas(

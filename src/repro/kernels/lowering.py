@@ -56,6 +56,16 @@ The downgrade ladder (each step recorded in ``Lowering.downgrade``):
      ``pallas_v1`` (the revisiting kernel's working set is per-pair).
   3. row-sharded partial, (B_r, B_c) Φ tile over budget at the minimum
      tile → the jnp oracle partial (there is no v1 partial formulation).
+
+One rung picks between the two v2 forwards; it is a choice, not a
+downgrade.  A ``pallas`` forward (not gathered, not v1) of a plan whose
+wiring is a permutation per level (the ``blockperm`` family) runs the
+input-stationary kernel (``Lowering.stationary``, variant
+``fwd_stationary``: each block of A read once) when its per-level slots,
+output block and stacked Φ fit the budget at the minimum tile
+(``stationary_fits``; they grow with k, not with n); every other forward
+keeps the output-stationary kernel.  Each record that takes it counts
+``lowering.fwd_input_stationary``.
 """
 from __future__ import annotations
 
@@ -67,8 +77,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import precision as precision_mod
-from repro.core.blockperm import (VMEM_BUDGET_BYTES, BlockPermPlan,
-                                  fused_variant_bytes)
+from repro.core.blockperm import (STATIONARY_VARIANT, VMEM_BUDGET_BYTES,
+                                  BlockPermPlan, fused_variant_bytes)
 from repro.health import report as health_report
 from repro.kernels import flashsketch as fsk
 from repro.kernels import ref as kref
@@ -148,6 +158,8 @@ class Lowering:
       * ``interpret`` — the Pallas kernel runs in interpret mode.  Set
         only for a Pallas impl off the TPU backend, and shown by
         ``describe()``: on a TPU every Pallas lowering compiles.
+      * ``stationary`` — the forward runs the input-stationary kernel
+        (``stationary_fits``); ``False`` for every other launch.
     """
 
     plan: BlockPermPlan
@@ -172,10 +184,13 @@ class Lowering:
     pad_rows: int
     pad_cols: int
     interpret: bool = False
+    stationary: bool = False
 
     @property
     def variant(self) -> str:
         """Tuner/VMEM shape-class name of the kernel that runs."""
+        if self.stationary:
+            return STATIONARY_VARIANT
         return self.op + ("_gather" if self.gather_fused else "")
 
     @property
@@ -189,6 +204,8 @@ class Lowering:
             bits[-1] += f"(req {self.impl_requested})"
         if self.interpret:
             bits.append("interpret")
+        if self.stationary:
+            bits.append("input-stationary")
         bits.append(f"tn={self.tn}:{self.tn_source}")
         bits.append(f"dtype={self.dtype}")
         if self.gather:
@@ -230,6 +247,7 @@ class Lowering:
             "pad_rows": self.pad_rows,
             "pad_cols": self.pad_cols,
             "interpret": self.interpret,
+            "stationary": self.stationary,
             "variant": self.variant,
             "version": self.version,
             "plan": {"d": p.d, "d_pad": p.d_pad, "k_pad": p.k_pad,
@@ -267,6 +285,23 @@ def partial_vmem_bytes(plan: BlockPermPlan, tn: int) -> int:
 def partial_fits_vmem(plan: BlockPermPlan, tn: int) -> bool:
     """Whether the partial kernel's working set fits the VMEM budget."""
     return partial_vmem_bytes(plan, tn) <= VMEM_BUDGET_BYTES
+
+
+def stationary_fits(plan: BlockPermPlan, n_eff: int) -> bool:
+    """Whether a ``pallas`` forward of ``plan`` over ``n_eff`` columns (on
+    one device, or per device of a column- or batch-sharded launch) runs
+    the input-stationary kernel: the wiring must be a permutation per
+    level (a ``blockperm`` plan — the global families' all-blocks wiring
+    is not), and its ``(κ, M, B_r, tn)`` slots, ``(M, B_r, tn)`` output
+    block and stacked Φ must fit the VMEM budget at the minimum tile.
+    None of these grows with n, so for a given plan the answer changes
+    only where the minimum tile does (n below one lane).
+    Level ℓ's tile sits at row ℓ·B_r of the stacked Φ, a slice the chip
+    takes only at whole sublane tiles of Φ's dtype (8 rows of 32 bits),
+    so B_r must be a multiple of that tile."""
+    sublanes = 8 * (4 // plan.precision.compute_itemsize)
+    return (not plan.is_global and plan.Br % sublanes == 0
+            and tune.fused_fits_vmem(plan, n_eff, STATIONARY_VARIANT))
 
 
 # ---------------------------------------------------------------------------
@@ -373,6 +408,7 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
 
     downgrade: Optional[str] = None
     gather_fused = False
+    stationary = False
     tn: Optional[int] = spec.tn
     tn_source = "explicit" if spec.tn is not None else "n/a"
     vmem: Optional[int] = None
@@ -458,6 +494,16 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
                     else reason
                 t(f"impl: 'pallas' -> 'pallas_v1' ({reason})")
                 impl = "pallas_v1"
+        if impl == "pallas" and spec.op == "fwd" and not gather_fused:
+            stationary = stationary_fits(eff, n_eff)
+            if stationary:
+                variant = STATIONARY_VARIANT
+                t("fwd: input-stationary kernel (permutation wiring; "
+                  "slots, output and stacked Φ fit at the minimum tile)")
+            else:
+                t("fwd: output-stationary kernel (global wiring, B_r not "
+                  "whole sublane tiles, or the input-stationary slots do "
+                  "not fit at the minimum tile)")
 
         if tn is None:
             if impl == "pallas_v1":
@@ -500,7 +546,7 @@ def _lower(plan: BlockPermPlan, spec: LaunchSpec,
         shard=spec.shard, devices=spec.devices if spec.shard != "none" else 1,
         n=spec.n, n_loc=n_loc, batch_loc=batch_loc, n_eff=n_eff,
         grid_cols=grid_cols, vmem_bytes=vmem, pad_rows=pad_rows, pad_cols=0,
-        interpret=impl in _PALLAS_IMPLS and not on_tpu)
+        interpret=impl in _PALLAS_IMPLS and not on_tpu, stationary=stationary)
 
 
 _LOWERING_CACHE: Dict[Tuple, Lowering] = {}
@@ -542,6 +588,8 @@ def lower(plan: BlockPermPlan, spec: LaunchSpec) -> Lowering:
     if hit is not None:
         return hit
     hit = _lower(plan, spec, None)      # pure; safe outside the lock
+    if hit.stationary:
+        health_report.record("lowering.fwd_input_stationary")
     with _MEMO_LOCK:
         # only memoize against the generation we resolved under — if the
         # tuner mutated mid-resolve, serve the result but do not cache it
@@ -629,8 +677,10 @@ _ORACLES = {
     "blockrow": kref.blockrow_ref,
 }
 
+# keyed by ``Lowering.variant``
 _V2_KERNELS = {
     "fwd": fsk.flashsketch_pallas,
+    STATIONARY_VARIANT: fsk.flashsketch_pallas_stationary,
     "transpose": fsk.flashsketch_transpose_pallas,
     "blockrow": fsk.blockrow_pallas,
 }
@@ -754,7 +804,7 @@ def _launch_body(lw: Lowering, operand: jnp.ndarray,
         out = _V1_KERNELS[lw.op](plan, _emulate_stream(plan, operand),
                                  tn=lw.tn, interpret=lw.interpret)
     else:
-        out = _V2_KERNELS[lw.op](plan, operand, tn=lw.tn,
-                                 interpret=lw.interpret)
+        out = _V2_KERNELS[lw.variant](plan, operand, tn=lw.tn,
+                                      interpret=lw.interpret)
     rows = plan.d if lw.op == "transpose" else plan.k
     return out[:rows, :n]

@@ -43,11 +43,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.blockperm import (GATHER_VARIANTS, LANE, SKETCH_VARIANTS,
-                                  BlockPermPlan, VMEM_BUDGET_BYTES,
-                                  _next_pow2, fused_variant_bytes, make_plan)
+                                  STATIONARY_VARIANT, BlockPermPlan,
+                                  VMEM_BUDGET_BYTES, _next_pow2,
+                                  fused_variant_bytes, make_plan)
 from repro.kernels import flashsketch as fsk
 
-VARIANTS = SKETCH_VARIANTS + GATHER_VARIANTS
+VARIANTS = SKETCH_VARIANTS + GATHER_VARIANTS + (STATIONARY_VARIANT,)
 
 _MAX_TN = 1024
 
@@ -136,7 +137,7 @@ def _vmem_footprint(plan: BlockPermPlan, tn: int, variant: str) -> int:
     return fused_variant_bytes(plan.kappa, plan.Br, plan.Bc, tn,
                                plan.stream_itemsize, variant,
                                plan.precision.compute_itemsize,
-                               gather_mask=plan.d < plan.d_pad)
+                               gather_mask=plan.d < plan.d_pad, M=plan.M)
 
 
 def legal_tns(n: int, variant: str = "fwd") -> Tuple[int, ...]:
@@ -206,19 +207,25 @@ def resolve(plan: BlockPermPlan, n: int, variant: str = "fwd",
             batch: int = 1, trace: Optional[list] = None) -> Tuple[int, str]:
     """``(tn, source)`` for a launch that did not pin its tile: the tuned or
     loaded winner of the shape class when it is a legal tile for this
-    exact column count (a class spans a bucket of ``n``), else the VMEM
-    heuristic.  Pure python, safe at trace time; ``trace`` collects the
-    reasoning for ``lowering.explain``."""
+    exact column count (a class spans a bucket of ``n``) and its working
+    set fits the VMEM budget (a loaded cache may come from another
+    budget or be damaged), else the VMEM heuristic.  Pure python, safe at
+    trace time; ``trace`` collects the reasoning for
+    ``lowering.explain``."""
     hit = lookup(plan, n, variant, batch=batch)
     if hit is not None:
-        if hit.tn in legal_tns(n * max(1, batch), variant):
+        fp = _vmem_footprint(plan, hit.tn, variant)
+        if hit.tn not in legal_tns(n * max(1, batch), variant):
+            why = f"is not a legal tile at {n * max(1, batch)} columns"
+        elif fp > VMEM_BUDGET_BYTES:
+            why = (f"needs {fp} B of VMEM > budget {VMEM_BUDGET_BYTES} B")
+        else:
             if trace is not None:
                 trace.append(f"tn: {hit.tn} ({hit.source} winner, class "
                              f"{variant!r}, batch={batch})")
             return hit.tn, hit.source
         if trace is not None:
-            trace.append(f"tn: {hit.source} winner {hit.tn} is not a legal "
-                         f"tile at {n * max(1, batch)} columns")
+            trace.append(f"tn: {hit.source} winner {hit.tn} {why}")
     tn = heuristic_tn(plan, n, variant, batch, trace=trace)
     if trace is not None:
         trace.append(f"tn: {tn} (heuristic, class {variant!r}, "
@@ -257,6 +264,7 @@ _KERNELS = {
     "fwd": fsk.flashsketch_pallas,
     "transpose": fsk.flashsketch_transpose_pallas,
     "blockrow": fsk.blockrow_pallas,
+    STATIONARY_VARIANT: fsk.flashsketch_pallas_stationary,
     "fwd_gather": _with_identity_row_map(fsk.flashsketch_pallas_gather),
     "blockrow_gather": _with_identity_row_map(fsk.blockrow_pallas_gather),
 }

@@ -92,8 +92,13 @@ def kernel_cost(
     tn: int = 128,
     gather: bool = False,
     batch: int = 1,
+    stationary: bool = False,
 ) -> KernelCost:
     """Single-launch cost terms.
+
+    ``stationary`` models the input-stationary forward: A is read from
+    HBM once instead of κ times, and Φ is built once per column tile (as
+    v1 does); the MXU term is unchanged.
 
     ``batch`` folds a B-stack into the column axis (the batched apply):
     every term scales with ``n·batch`` but the Φ build cost stays per-launch
@@ -135,14 +140,16 @@ def kernel_cost(
     # column-pattern kernels, Br for blockrow's per-row pattern).
     words = p.Br if variant == "blockrow" else p.Bc
     per_tile = HASH_OPS_PER_WORD * p.s * words
-    tile_builds = p.kappa * p.M * (n_tiles if version == "v1" else 1)
+    tile_builds = p.kappa * p.M * (n_tiles if version == "v1" or stationary
+                                   else 1)
     vpu = per_tile * tile_builds
 
     if variant == "transpose":
         in_elems = p.kappa * p.k_pad * n_eff  # Y gathered κ× via inverse maps
         out_elems = p.d_pad * n_eff
     else:
-        in_elems = p.kappa * p.d_pad * n_eff  # A streamed κ×
+        # A streamed κ× (once by the input-stationary forward)
+        in_elems = (1 if stationary else p.kappa) * p.d_pad * n_eff
         out_elems = p.k_pad * n_eff
     out_accesses = (2 * p.kappa - 1) * out_elems if version == "v1" else out_elems
     if gather:
@@ -192,6 +199,7 @@ def cost_of(lw) -> KernelCost:
         lw.plan, lw.n_loc,
         version=lw.version, variant=lw.op, tn=tn,
         gather=lw.gather_fused, batch=lw.batch_loc,
+        stationary=lw.stationary,
     )
 
 

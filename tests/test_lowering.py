@@ -215,7 +215,8 @@ def test_cost_of_agrees_with_legacy_kernel_cost():
                 version="v1" if lw.impl == "pallas_v1" else "v2",
                 variant=lw.op,
                 tn=lw.tn if lw.tn is not None else 128,
-                gather=lw.gather_fused, batch=lw.batch_loc)
+                gather=lw.gather_fused, batch=lw.batch_loc,
+                stationary=lw.stationary)
         got = sketch_model.cost_of(lw)
         assert got == want, (plan_name, spec_kwargs)
         checked += 1
@@ -348,8 +349,9 @@ def test_lowering_cache_hits_and_tuner_invalidation():
     a = lowering.lower(plan, spec)
     b = lowering.lower(plan, spec)
     assert a is b                              # memoized record
-    # a tuned winner landing bumps the generation and re-resolves
-    key = tune.cache_key(plan, 200, "fwd")
+    # a tuned winner landing bumps the generation and re-resolves (the
+    # key of the kernel the record runs: the input-stationary forward)
+    key = tune.cache_key(plan, 200, a.variant)
     tune._CACHE[key] = tune.TuneResult(tn=128, time_us=1.0, source="tuned")
     tune._bump_generation()
     c = lowering.lower(plan, spec)
@@ -511,7 +513,7 @@ def test_lowering_sees_freshly_loaded_winner(tmp_path):
     spec = lowering.LaunchSpec(op="fwd", n=300, impl="pallas")
     before = lowering.lower(plan, spec)
     assert before.tn_source == "heuristic" and before.tn != 128
-    tune._CACHE[tune.cache_key(plan, 300, "fwd")] = tune.TuneResult(
+    tune._CACHE[tune.cache_key(plan, 300, before.variant)] = tune.TuneResult(
         tn=128, time_us=1.0, source="tuned")
     path = str(tmp_path / "cache.json")
     tune.save_cache(path)

@@ -273,8 +273,12 @@ def test_sketch_vectors_uses_batched_cache_winner(monkeypatch, rng):
     tune.clear_cache()
     plan = make_plan(256, 64, kappa=2, s=2, block_rows=8, seed=4)
     B = 300
-    key = tune.cache_key(plan, 1, "fwd", batch=B)
+    # the shape class of the forward kernel these calls run
+    variant = lowering.lower(plan, lowering.LaunchSpec(
+        op="fwd", n=1, impl="pallas", batch=B)).variant
+    key = tune.cache_key(plan, 1, variant, batch=B)
     tune._CACHE[key] = tune.TuneResult(tn=128, time_us=1.0, source="tuned")
+    tune._bump_generation()
     try:
         seen = []
         orig = lowering.execute

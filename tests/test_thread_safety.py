@@ -11,15 +11,16 @@ import threading
 
 import pytest
 
-from repro.core.blockperm import make_plan
+from repro.core.blockperm import STATIONARY_VARIANT, make_plan
 from repro.kernels import lowering, tune
 
 
-def _cache_file(tmp_path, plans, n=256, tn=128):
+def _cache_file(tmp_path, plans, n=256, tn=128,
+                variants=("fwd", "transpose")):
     """A valid tuner-cache JSON with one row per (plan, variant)."""
     payload = {}
     for plan in plans:
-        for variant in ("fwd", "transpose"):
+        for variant in variants:
             key = tune.cache_key(plan, n, variant)
             payload[json.dumps(list(key))] = {
                 "tn": tn, "block_rows": None, "time_us": 1.0,
@@ -89,7 +90,9 @@ def test_tuner_cache_concurrent_load_save_clear(tmp_path):
 
 def test_lowering_memo_concurrent_with_generation_flushes(tmp_path):
     plans = [make_plan(512, 64, kappa=2, s=2, seed=sd) for sd in range(6)]
-    src = _cache_file(tmp_path, plans, tn=128)
+    # rows for both forwards: these plans take the input-stationary one
+    src = _cache_file(tmp_path, plans, tn=128,
+                      variants=("fwd", STATIONARY_VARIANT, "transpose"))
     # impl="pallas": the auto path lowers to the tile-less xla oracle on
     # CPU; the pallas (interpret-mode) path exercises tile resolution
     specs = [lowering.LaunchSpec(op="fwd", n=256, impl="pallas", batch=b)

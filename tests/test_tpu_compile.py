@@ -23,7 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.blockperm import VMEM_BUDGET_BYTES, make_plan
-from repro.distributed import plan_for_mesh, sketch_apply_sharded
+from repro.distributed import (plan_for_mesh, sketch_apply_colsharded,
+                               sketch_apply_sharded)
 from repro.kernels import flashsketch as fsk
 from repro.kernels import lowering, ops
 
@@ -118,6 +119,43 @@ def test_tightest_default_plan_compiles(op, one_chip, chip, monkeypatch):
     apply = {"fwd": ops.sketch_apply, "transpose": ops.sketch_apply_t,
              "blockrow": ops.blockrow_apply}[op]
     _compile_at_modeled_vmem(monkeypatch, lw, lambda X: apply(plan, X), x)
+
+
+@pytest.mark.parametrize("d,k,n", [
+    (262_144, 1024, 512),          # the sketch.apply cells' plan
+    (262_144, 1024, 600),          # two column tiles, the second ragged
+    (552_960, 4096, 8),            # the Moonlight GraSS cell's sketch
+])
+def test_input_stationary_forward_compiles(d, k, n, one_chip, chip,
+                                           monkeypatch):
+    """The input-stationary forward at the benchmark cells' plans, at the
+    tile and VMEM figure that ``explain`` reports."""
+    plan = make_plan(d, k, kappa=4, s=2, seed=0)
+    lw = lowering.lower(plan, lowering.LaunchSpec(op="fwd", n=n))
+    assert lw.stationary, lw.describe()
+    text = lowering.explain(plan, n=n)
+    assert "fwd: input-stationary kernel" in text, text
+    assert f"tn={lw.tn}:" in text and f"vmem={lw.vmem_bytes}B" in text, text
+    A = jax.ShapeDtypeStruct((d, n), jnp.float32, sharding=one_chip)
+    _compile_at_modeled_vmem(monkeypatch, lw,
+                             lambda X: ops.sketch_apply(plan, X), A)
+
+
+def test_input_stationary_forward_compiles_col_sharded_on_four_chips(
+        topo, chip, monkeypatch):
+    """Each device of a column-sharded forward runs the input-stationary
+    kernel on its own columns, inside ``shard_map``."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("shard",))
+    d, k, n = 262_144, 1024, 2048
+    plan = make_plan(d, k, kappa=4, s=2, seed=0)
+    lw = lowering.lower(plan, lowering.LaunchSpec(
+        op="fwd", n=n, shard="col", devices=4))
+    assert lw.stationary and lw.n_eff == n // 4, lw.describe()
+    A = jax.ShapeDtypeStruct((d, n), jnp.float32,
+                             sharding=NamedSharding(mesh, P(None, "shard")))
+    _compile_at_modeled_vmem(
+        monkeypatch, lw,
+        lambda X: sketch_apply_colsharded(plan, X, mesh, "shard"), A)
 
 
 def test_solve_sketch_compiles(one_chip, chip, monkeypatch):

@@ -143,9 +143,10 @@ def test_memo_counters_count_lookups(plan):
     spec = lowering.LaunchSpec(op="fwd", n=96, impl="pallas")
     first = lowering.lower(plan, spec)
     second = lowering.lower(plan, spec)
-    assert first is second
+    assert first is second and first.stationary
     assert report.counters() == {"lowering.memo_miss": 1,
-                                 "lowering.memo_hit": 1}
+                                 "lowering.memo_hit": 1,
+                                 "lowering.fwd_input_stationary": 1}
     assert "lowering.memo_hit=1" in lowering.explain(plan, spec)
 
 
